@@ -6,6 +6,7 @@ from __future__ import annotations
 import argparse
 
 from benchmarks.common import WorldConfig, build_world, run_method, save_results
+from repro.utils.compile_cache import enable_compile_cache
 
 ROWS = ["dechetero", "decdiff", "decdiff+vt", "dechetero+vt", "cfa", "cfa+vt"]
 
@@ -51,4 +52,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

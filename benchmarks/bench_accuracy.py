@@ -18,6 +18,7 @@ from benchmarks.common import (
     run_method,
     save_results,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 METHODS = ["isol", "fedavg", "dechetero", "cfa", "cfa-ge", "decdiff", "decdiff+vt"]
 
@@ -74,4 +75,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

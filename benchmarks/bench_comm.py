@@ -34,6 +34,7 @@ from repro.engine import Experiment, Schedule, World
 from repro.fl.metrics import comm_bytes_per_round
 from repro.graphs import make_topology
 from repro.models.mlp_cnn import make_cnn, make_mlp
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.pytree import tree_bytes, tree_size
 
 METHODS = ["isol", "fedavg", "dechetero", "cfa", "cfa-ge", "decdiff", "decdiff+vt"]
@@ -219,4 +220,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,7 @@ from repro.data.allocation import split_by_allocation
 from repro.engine import Experiment, Schedule, World
 from repro.graphs import make_topology
 from repro.models.mlp_cnn import model_for_dataset
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(num_nodes=24, rounds=8, data_scale=0.06, verbose=True):
@@ -56,4 +57,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

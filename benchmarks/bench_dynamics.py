@@ -38,6 +38,7 @@ from repro.dynamics import (
     StaticGraph,
 )
 from repro.engine import Experiment, Schedule, World
+from repro.utils.compile_cache import enable_compile_cache
 
 ROUNDS = 40
 EVAL_EVERY = 5
@@ -140,4 +141,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

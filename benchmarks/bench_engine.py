@@ -36,6 +36,7 @@ import time
 from benchmarks.common import load_results, save_results
 from repro.comm import CommConfig
 from repro.engine import Experiment, Schedule, World
+from repro.utils.compile_cache import enable_compile_cache
 
 ROUNDS = 60
 EVAL_EVERY = 10
@@ -222,4 +223,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

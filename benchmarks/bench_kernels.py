@@ -15,6 +15,7 @@ import numpy as np
 
 from benchmarks.common import save_results
 from repro.kernels.ref import decdiff_update_ref, neighbor_avg_ref, vt_kl_loss_ref
+from repro.utils.compile_cache import enable_compile_cache
 
 HBM_BW = 819e9
 
@@ -74,4 +75,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

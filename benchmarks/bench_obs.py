@@ -40,6 +40,7 @@ from repro.comm import CommConfig
 from repro.engine import Experiment, Schedule, World
 from repro.obs import Telemetry, export_trace, validate_ledger
 from repro.timing import LognormalLink, LognormalStep, Timing
+from repro.utils.compile_cache import enable_compile_cache
 
 ROUNDS = 40
 EVAL_EVERY = 10
@@ -179,4 +180,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

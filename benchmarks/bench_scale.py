@@ -39,6 +39,7 @@ import time
 import numpy as np
 
 from benchmarks.common import save_results
+from repro.utils.compile_cache import enable_compile_cache
 
 # dense is swept while its padded neighbour block stays under this budget;
 # past it the row records the projection, not an OOM.
@@ -266,4 +267,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -42,6 +42,7 @@ from repro.timing import (
     StragglerStep,
     Timing,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 ROUNDS = 40
 EVAL_EVERY = 5
@@ -198,4 +199,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ from repro.data.allocation import split_by_allocation
 from repro.engine import Experiment, Schedule, World
 from repro.graphs import make_topology
 from repro.models.mlp_cnn import model_for_dataset
+from repro.utils.compile_cache import enable_compile_cache
 
 TOPOLOGIES = [
     ("erdos_renyi", dict(p=0.25)),
@@ -71,4 +72,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

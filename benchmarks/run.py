@@ -11,6 +11,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -18,6 +20,7 @@ def main() -> None:
     ap.add_argument("--skip-sim", action="store_true",
                     help="skip the multi-minute simulation benches")
     args = ap.parse_args()
+    enable_compile_cache()
 
     csv = [("name", "us_per_call", "derived")]
 

@@ -60,8 +60,7 @@ state (the `last_sent` reconstruction caches, the ever-sent/-delivered
 flags) is REPLICATED: every pod recomputes the full-axis update from the
 gathered wire deterministically, so the replicas cannot diverge and the
 reverse-slot gather (receiver r reads sender j's slot toward r — resolved
-by the `repro.kernels` gather-rows kernel over the flattened per-link
-table) never crosses pods at aggregation time.  `state_specs` hands the
+by one row gather over the flattened per-link table) never crosses pods at aggregation time.  `state_specs` hands the
 engine the matching PartitionSpec tree.
 
 What the gather carries is the `wire` choice: ``"encoded"`` (the default)
@@ -445,9 +444,8 @@ class EdgeGossipTransport:
     *reverse* slot map: receiver r hearing neighbour j at slot e reads
     sender j's edge state at slot rev[r, e] (the slot of r in j's list).
     The gather itself — receiver rows out of the flattened [N*E, D]
-    per-link reference table — runs through the `repro.kernels` gather-rows
-    Pallas kernel on every backend (a pure copy, bitwise identical to fancy
-    indexing).
+    per-link reference table — is one XLA row gather (`jnp.take`) on every
+    backend (a pure copy, bitwise identical to fancy indexing).
     """
 
     def __init__(self, config: CommConfig, stacked_params,
@@ -583,14 +581,12 @@ class EdgeGossipTransport:
     def _gather_receiver_rows(self, new_last_full, rows):
         """The reverse-slot gather: receiver row r's slot e reads sender
         nbr_idx[r, e]'s reference at slot rev_slot[r, e] out of the full
-        per-link table — the gather-rows Pallas kernel over the flattened
-        [N*E, D] view (a pure copy; bitwise identical to fancy indexing)."""
-        from repro.kernels.ops import gather_rows
-
+        per-link table — one XLA row gather over the flattened [N*E, D]
+        view (a pure copy; bitwise identical to fancy indexing)."""
         flat_idx = (rows(self.nbr_idx) * self.e + rows(self.rev_slot))
         r = int(flat_idx.shape[0])
-        gathered = gather_rows(new_last_full.reshape(self.n * self.e, self.d),
-                               flat_idx.reshape(-1))
+        gathered = jnp.take(new_last_full.reshape(self.n * self.e, self.d),
+                            flat_idx.reshape(-1), axis=0)
         gathered = self._unflatten(gathered)
         return jax.tree.map(
             lambda l: l.reshape((r, self.e) + l.shape[1:]), gathered)

@@ -18,7 +18,7 @@ step size automatically shrinks when models are topologically far apart
 (early rounds / heterogeneous init) and grows as they converge.
 
 Everything here operates on pytrees; distances are accumulated leafwise in
-fp32.  For sharded (pjit/shard_map) execution see `repro.dist.dfl_step`,
+fp32.  For sharded (jit/shard_map) execution see `repro.dist.dfl_step`,
 which applies the same update over a stacked node axis (vmapped, or
 shard_mapped over the pod ring).
 """

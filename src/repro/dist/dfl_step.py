@@ -30,7 +30,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.comm.codecs import Int8Codec
@@ -199,10 +198,9 @@ def build_dfl_round_shardmap(lm, opt, adj, mesh, *, loss_kind: str = "vt",
 
     Each pod holds `N / n_pods` nodes; the gossip exchange is an all_gather
     of the post-step models over the pod ring (cast to `gossip_dtype` first
-    when set).  All mesh axes are manual — jaxlib 0.4.3x's partitioner
-    CHECK-fails on shard_map with `auto` non-pod axes — so each pod holds
-    its nodes' full replicas and Eq. 5's global squared norm is complete
-    blockwise, no cross-axis reduction needed.  Delivery masks follow
+    when set).  All mesh axes are manual, so each pod holds its nodes' full
+    replicas and Eq. 5's global squared norm is complete blockwise, no
+    cross-axis reduction needed.  Delivery masks follow
     `build_dfl_round`: a baked builder `mask` plus an optional runtime
     `mask` argument on the round function.  Falls back to the vmap
     formulation when the mesh has no pod axis.
@@ -294,11 +292,11 @@ def build_dfl_round_shardmap(lm, opt, adj, mesh, *, loss_kind: str = "vt",
         loss = jax.lax.pmean(jnp.mean(losses), NODE_AXIS)
         return out, new_state, loss
 
-    sharded = shard_map(
-        block, mesh,
+    sharded = jax.shard_map(
+        block, mesh=mesh,
         in_specs=(P(NODE_AXIS), P(NODE_AXIS), P(), P(NODE_AXIS), P()),
         out_specs=(P(NODE_AXIS), P(NODE_AXIS), P()),
-        check_rep=False)
+        check_vma=False)
 
     def round_fn(params, opt_state, step, batch, mask=None):
         m = mask if mask is not None else built_mask

@@ -25,7 +25,7 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 DATA_AXIS = "data"
@@ -43,6 +43,25 @@ _STACK_LEAD = {"layers": 1, "enc_layers": 1, "dec_layers": 1, "mamba": 2}
 # MoE expert weights [L, E, D, F]: with expert parallelism the E dim shards
 # over "model" (experts live on model shards; dispatch becomes an all-to-all).
 _EXPERT_KEYS = {"wg", "wu", "wd"}
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """`jax.make_mesh` with every axis `Auto`.
+
+    The engines place data through shard_map specs and jit propagation, not
+    through sharding-in-types; on an `Explicit` axis (jax.make_mesh's
+    default) the pod-sharded params a shard_map round returns cannot enter
+    a jit traced outside the mesh, e.g. the per-node eval."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """The same devices and axis names as `mesh`, every axis `Auto`."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def _axis_size(mesh, name: str) -> int:

@@ -94,7 +94,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.comm import (DENSE_CTX, EdgeGossipTransport, PodContext,
@@ -907,7 +906,7 @@ def _build_vmap_round(exp):
 def _build_shardmap_round(exp):
     """The same round body shard_mapped over the pod axis.
 
-    All mesh axes are manual (`check_rep=False`) following
+    All mesh axes are manual (`check_vma=False`) following
     `repro.dist.dfl_step.build_dfl_round_shardmap`; each pod holds its
     nodes' full replicas, so per-node reductions (Eq. 5's global norm, the
     trigger's drift) are complete blockwise and only the exchange's gather
@@ -982,12 +981,12 @@ def _build_shardmap_round(exp):
         return _squeeze(body(make_ctx(), params, opt, comm_state, dyn_state,
                              time_state, obs_state, round_idx, rng, x, y))
 
-    sharded = shard_map(
-        block, mesh,
+    sharded = jax.shard_map(
+        block, mesh=mesh,
         in_specs=(shard, shard) + state_specs + (rep, rep, shard, shard),
         out_specs=((shard, shard) + state_specs + (rep, rep)
                    + (rep,) * n_extras),
-        check_rep=False)
+        check_vma=False)
 
     def round_fn(params, opt, *rest):
         return sharded(params, opt, *rest, exp.x_pad, exp.y_pad)

@@ -52,7 +52,7 @@ from repro.comm import (WIRES, CommConfig, EdgeGossipTransport,
 from repro.core.virtual_teacher import make_loss_fn
 from repro.data.allocation import pad_node_datasets
 from repro.data.pipeline import Batcher
-from repro.dist.sharding import NODE_AXIS
+from repro.dist.sharding import NODE_AXIS, auto_mesh, make_mesh
 from repro.dynamics import GraphProcess
 from repro.engine import backends
 from repro.engine.neighborhood import build_sparse_plan
@@ -200,13 +200,16 @@ class World:
 
 
 def _default_mesh(n: int):
-    """A pure pod mesh over the local devices: the largest pod count that
-    tiles the node axis (1 pod on a single-device host — the shard_map
-    lowering then still runs, just without an actual exchange axis split)."""
+    """A pure pod mesh over ALL local devices (1 pod on a single-device
+    host — the shard_map lowering then still runs, just without an actual
+    exchange axis split).  Raises rather than leave devices idle when the
+    node count does not tile them; pass `mesh=` to use fewer."""
     d = len(jax.devices())
-    while n % d:
-        d -= 1
-    return jax.make_mesh((d,), (NODE_AXIS,))
+    if n % d:
+        raise ValueError(
+            f"{n} DFL nodes do not tile the {d} local devices; pass mesh= "
+            f"with a pod count that divides {n}")
+    return make_mesh((d,), (NODE_AXIS,))
 
 
 class Experiment:
@@ -288,7 +291,7 @@ class Experiment:
         self.model = model
         self.topo = topo
         self.n = topo.num_nodes
-        self.mesh = (mesh if mesh is not None else
+        self.mesh = (auto_mesh(mesh) if mesh is not None else
                      _default_mesh(self.n) if backend == "shard_map" else None)
 
         x_pad, y_pad, counts = pad_node_datasets(world.xs, world.ys)
@@ -643,22 +646,38 @@ class Experiment:
         if verbose:
             log_round(self.method.name, m)
 
+    def _carry(self):
+        return (self.params, self.opt_state) + self._get_states() \
+            + (self.rng,)
+
+    def compile(self, rounds: Optional[int] = None,
+                eval_every: Optional[int] = None):
+        """AOT-lower and compile the fused schedule program for the current
+        state — the SAME jitted program `run()` dispatches (same jaxpr,
+        donation honored) — and keep the executable for every later
+        `run()` of that schedule.  Returns it (`.as_text()` is the
+        optimized HLO); compiling outside `run()` separates the compile
+        seconds from the dispatch."""
+        rounds = self.schedule.rounds if rounds is None else rounds
+        eval_every = (self.schedule.eval_every if eval_every is None
+                      else eval_every)
+        fused = self._fused_program(rounds, eval_every)
+        if isinstance(fused, jax.stages.Compiled):
+            return fused
+        compiled = fused.lower(self._carry()).compile()
+        self._fused_cache[(rounds, eval_every)] = compiled
+        return compiled
+
     def _run_fused(self, rounds, eval_every, verbose) -> List[RoundMetrics]:
         cold = (rounds, eval_every) not in self._fused_cache
-        fused = self._fused_program(rounds, eval_every)
         n_states = sum(self._state_flags())
-        carry = (self.params, self.opt_state) + self._get_states() \
-            + (self.rng,)
         if self.ledger is not None and cold:
-            # compile-time counter for the ledger: AOT-lower and compile
-            # the SAME jitted program (same jaxpr, donation honored) so
-            # the compile seconds are separable from the dispatch; the
-            # compiled executable replaces the cache entry and serves
-            # every later call.
+            # compile-time counter for the ledger
             t0 = _time.perf_counter()
-            fused = fused.lower(carry).compile()
+            self.compile(rounds, eval_every)
             self._compile_s = _time.perf_counter() - t0
-            self._fused_cache[(rounds, eval_every)] = fused
+        fused = self._fused_program(rounds, eval_every)
+        carry = self._carry()
         self._cold_compile = cold
         carry, ys = fused(carry)
         self.params, self.opt_state = carry[:2]

@@ -12,11 +12,8 @@
                    all_gathered payload)
   decode_attention — fused one-token GQA attention over the ring KV cache
                    (the serving hot spot; online softmax over cache tiles)
-  gather_rows    — row gather out of a flattened table (the cross-pod
-                   reverse-slot resolution of the per-edge exchange: a pure
-                   copy, bitwise identical to fancy indexing)
 
-`ops` holds the jit'd public wrappers (auto interpret=True off-TPU);
+`ops` holds the jit'd public wrappers (compiled on TPU, interpreted on CPU);
 `ref` holds the pure-jnp oracles the tests sweep against.
 """
 from repro.kernels.ops import (  # noqa: F401
@@ -25,7 +22,6 @@ from repro.kernels.ops import (  # noqa: F401
     decode_attention_fused,
     dequant_neighbor_avg,
     dequant_neighbor_avg_rows,
-    gather_rows,
     neighbor_avg,
     vt_kl_loss_fused,
 )

@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handles padding to tile boundaries, the pytree <-> flat-stream view, the
-custom_vjp wiring for the fused VT loss, and automatic `interpret=True` when
-not running on TPU (this container is CPU-only; interpret mode executes the
-kernel bodies in Python for correctness validation).
+custom_vjp wiring for the fused VT loss, and the `interpret` default: kernels
+compile for the TPU and run in interpret mode on the CPU, where the tests
+check them (see `_interpret_default`).
 """
 from __future__ import annotations
 
@@ -20,7 +20,14 @@ from repro.utils.pytree import tree_flatten_to_vector
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on the TPU, interpreted on the CPU (tests); any other
+    backend has no Mosaic lowering and is refused, never interpreted."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels compile for the TPU and run interpreted on the "
+            f"CPU; backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 def _pad_to(x, multiple, value=0.0):
@@ -142,29 +149,6 @@ def decode_attention_fused(q, k_cache, v_cache, slot_pos, pos, interpret=None):
     return out[:b]
 
 
-# ------------------------------------------------------------- gather rows
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_rows(tbl, idx, interpret=None):
-    """Row gather `tbl[idx]` via the streaming Pallas kernel.
-
-    tbl [M, D], idx [K] int row ids -> [K, D] (tbl's dtype preserved).
-    The unified exchange's reverse-slot resolution: tbl is the flattened
-    [N*max_deg, D] per-link reference table, idx the receivers' flattened
-    (nbr, rev_slot) pairs.  A pure copy — bitwise identical to `tbl[idx]`.
-    """
-    from repro.kernels.gather_rows import COLS, gather_rows_blocks
-
-    interpret = _interpret_default() if interpret is None else interpret
-    d = tbl.shape[1]
-    pad = (-d) % COLS
-    tp = jnp.pad(tbl.astype(jnp.float32), ((0, 0), (0, pad)))
-    out = gather_rows_blocks(tp, idx.astype(jnp.int32),
-                             interpret=interpret)
-    return out[:, :d].astype(tbl.dtype)
-
-
 # ------------------------------------------------------------- neighbor avg
 
 
@@ -218,6 +202,8 @@ def segment_neighbor_avg(vals, w, interpret=None):
     `lax.map` drives fixed ROWS-row chunks so the kernel traces once: the
     result is bitwise invariant to B, chunking, and K zero-padding — the
     dense engine at small N is therefore an exact oracle for this path.
+    K is zero-padded to a multiple of 8 so the kernel's dot always has a
+    sublane-aligned contraction width, whatever the graph's degrees.
     """
     from repro.kernels import segment_avg as _sa
 
@@ -225,9 +211,11 @@ def segment_neighbor_avg(vals, w, interpret=None):
     b, k, d = vals.shape
     v2 = jnp.concatenate([vals.astype(jnp.float32),
                           jnp.ones((b, k, 1), jnp.float32)], axis=2)
-    v2 = jnp.pad(v2, ((0, (-b) % _sa.ROWS), (0, 0), (0, (-(d + 1)) % _sa.COLS)))
-    wp = jnp.pad(w.astype(jnp.float32), ((0, (-b) % _sa.ROWS), (0, 0)))
-    bp, dp = v2.shape[0], v2.shape[2]
+    pk = (-k) % _sa.K_ALIGN
+    v2 = jnp.pad(v2, ((0, (-b) % _sa.ROWS), (0, pk),
+                      (0, (-(d + 1)) % _sa.COLS)))
+    wp = jnp.pad(w.astype(jnp.float32), ((0, (-b) % _sa.ROWS), (0, pk)))
+    bp, k, dp = v2.shape
     out = jax.lax.map(
         lambda args: _sa.segment_avg_chunk(args[0], args[1],
                                            interpret=interpret),
@@ -252,11 +240,12 @@ def dequant_segment_neighbor_avg(q, scales, w, interpret=None):
 
     interpret = _interpret_default() if interpret is None else interpret
     b, k, d = q.shape
+    pk = (-k) % _sa.K_ALIGN
     qp = jnp.pad(q.astype(jnp.int8),
-                 ((0, (-b) % _sa.ROWS), (0, 0), (0, (-d) % _sa.COLS)))
+                 ((0, (-b) % _sa.ROWS), (0, pk), (0, (-d) % _sa.COLS)))
     ws = w.astype(jnp.float32) * scales.astype(jnp.float32)
-    wsp = jnp.pad(ws, ((0, (-b) % _sa.ROWS), (0, 0)))
-    bp, dp = qp.shape[0], qp.shape[2]
+    wsp = jnp.pad(ws, ((0, (-b) % _sa.ROWS), (0, pk)))
+    bp, k, dp = qp.shape
     out = jax.lax.map(
         lambda args: _sa.dequant_segment_avg_chunk(args[0], args[1],
                                                    interpret=interpret),

@@ -6,7 +6,7 @@ each receiver's neighbour rows into slot-padded blocks `[B, K, D]` (K =
 bucket width, degree-dependent) and reduces them here.
 
 Bitwise contract: each receiver row is contracted by its OWN unrolled
-`einsum("k,kd->d")` GEMV inside the kernel body.  A batched contraction's
+`(1, K) · (K, cols)` GEMV inside the kernel body.  A batched contraction's
 bits depend on the batch geometry (probed: `einsum("bk,bkd->bd")` at B=100
 differs from the same rows at B=1), so per-row unrolling is what makes the
 result invariant to how receivers are blocked into chunks, pods, or degree
@@ -29,20 +29,27 @@ from jax.experimental import pallas as pl
 
 ROWS = 8  # receiver rows per chunk (fixed so every call shares one geometry)
 COLS = 256  # feature columns per grid tile
+K_ALIGN = 8  # callers zero-pad the slot axis K to a multiple of this
+
+
+def _row_dot(w, v):
+    """One receiver row's own contraction: (1, K) · (K, cols) -> (1, cols).
+
+    Rank-2 on both sides, because Mosaic lowers no rank-1 dot; HIGHEST so
+    the MXU keeps fp32 accuracy."""
+    return jnp.dot(w, v, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def _segment_avg_kernel(w_ref, v_ref, o_ref):
-    o_ref[...] = jnp.stack([
-        jnp.einsum("k,kd->d", w_ref[r], v_ref[r],
-                   preferred_element_type=jnp.float32)
-        for r in range(ROWS)])
+    for r in range(ROWS):
+        o_ref[r:r + 1, :] = _row_dot(w_ref[r:r + 1, :], v_ref[r])
 
 
 def _dequant_segment_avg_kernel(ws_ref, q_ref, o_ref):
-    o_ref[...] = jnp.stack([
-        jnp.einsum("k,kd->d", ws_ref[r], q_ref[r].astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
-        for r in range(ROWS)])
+    for r in range(ROWS):
+        o_ref[r:r + 1, :] = _row_dot(ws_ref[r:r + 1, :],
+                                     q_ref[r].astype(jnp.float32))
 
 
 def _cols(dp: int, interpret: bool) -> int:

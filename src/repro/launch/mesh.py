@@ -11,18 +11,20 @@ from __future__ import annotations
 
 import jax
 
+from repro.dist.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int = 1, model: int = 1):
     """Tiny mesh on the real local devices (tests / examples)."""
     n = len(jax.devices())
     data = min(data, n)
-    return jax.make_mesh((data, max(1, min(model, n // data))), ("data", "model"))
+    return make_mesh((data, max(1, min(model, n // data))), ("data", "model"))
 
 
 HW = dict(  # TPU v5e constants used by the roofline analysis

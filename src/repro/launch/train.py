@@ -30,6 +30,7 @@ from repro.data.tokens import synthetic_token_batch
 from repro.dist.dfl_step import build_dfl_round, build_train_step
 from repro.models.lm import build_lm
 from repro.optim.sgd import sgd_momentum
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.pytree import tree_size
 
 
@@ -63,6 +64,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.preset == "reduced":

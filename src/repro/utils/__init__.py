@@ -1,1 +1,1 @@
-from repro.utils import pytree  # noqa: F401
+from repro.utils import compile_cache, pytree  # noqa: F401

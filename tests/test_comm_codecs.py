@@ -181,7 +181,8 @@ def test_edge_delivery_composes_gate_and_links():
 
 
 if HAVE_HYPOTHESIS:
-    finite = st.floats(min_value=-1e30, max_value=1e30,
+    big = float(np.float32(1e30))  # width=32 bounds must be float32 values
+    finite = st.floats(min_value=-big, max_value=big,
                        allow_nan=False, allow_infinity=False, width=32)
     vectors = st.lists(finite, min_size=1, max_size=300).map(
         lambda xs: jnp.asarray(xs, jnp.float32))

@@ -361,6 +361,22 @@ def test_fused_schedule_continues_across_runs(tiny_world):
     assert _params_equal(a.params, b.params)
 
 
+
+def test_compile_ahead_serves_the_fused_run(tiny_world):
+    """`compile()` AOT-builds the program `run()` dispatches: compiling
+    twice returns the same executable, and a run after it is bit-equal to
+    a run that compiled on first call."""
+    a = _exp(tiny_world, rounds=3, mode="fused")
+    compiled = a.compile()
+    assert a.compile() is compiled
+    assert " while(" in compiled.as_text()  # the rounds loop
+    ha = a.run()
+    b = _exp(tiny_world, rounds=3, mode="fused")
+    hb = b.run()
+    assert _params_equal(a.params, b.params)
+    for x, y in zip(ha, hb):
+        assert np.array_equal(x.acc_per_node, y.acc_per_node)
+
 # -------------------------------------------------- backend equivalence
 
 

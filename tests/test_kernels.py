@@ -239,29 +239,16 @@ def test_decode_attention_ref_matches_model_layer():
 
 
 # ---------------------------------------------------------------------------
-# gather_rows — exchange receiver-row gather (cross-pod reverse-slot path)
+# exchange receiver-row gather (reverse-slot path of the per-edge transport)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("m,k,d", [(8, 8, 16), (24, 96, 40), (16, 5, 2048), (12, 48, 3000)])
-def test_gather_rows_matches_fancy_indexing(m, k, d):
-    from repro.kernels.ops import gather_rows
-
-    rng = np.random.default_rng(7)
-    tbl = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
-    idx = jnp.asarray(rng.integers(0, m, size=(k,)), jnp.int32)
-    out = gather_rows(tbl, idx, interpret=True)
-    ref = tbl[idx]
-    assert out.dtype == tbl.dtype
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
 def test_gather_rows_reverse_slot_roundtrip():
-    """Gathering rev-slot indices out of a flattened [N*E, D] table reproduces
-    the dense _swap_layout on a symmetric neighbour layout."""
+    """The per-edge transport's reverse-slot row gather out of the flattened
+    [N*E, D] table reproduces the dense _swap_layout on a symmetric
+    neighbour layout, bit for bit."""
     from repro.comm import CommConfig
     from repro.comm.transport import EdgeGossipTransport
     from repro.graphs.topology import make_topology
-    from repro.kernels.ops import gather_rows
 
     topo = make_topology("ring", n=6)
     d = 10
@@ -271,7 +258,23 @@ def test_gather_rows_reverse_slot_roundtrip():
     n, e = tr.n, tr.e
     rng = np.random.default_rng(3)
     tbl = jnp.asarray(rng.standard_normal((n, e, d)), jnp.float32)
-    flat_idx = (tr.nbr_idx * e + tr.rev_slot).reshape(-1).astype(jnp.int32)
-    out = gather_rows(tbl.reshape(n * e, d), flat_idx, interpret=True).reshape(n, e, d)
+    out = tr._gather_receiver_rows(tbl, lambda a: a)["w"]
     ref = tbl[tr.nbr_idx, tr.rev_slot]
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
+# interpret default — compiled on the TPU, interpreted on the CPU, else refused
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_default_by_backend(monkeypatch, backend, interpret):
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret_default()
+    else:
+        assert ops._interpret_default() is interpret

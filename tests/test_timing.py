@@ -473,9 +473,9 @@ def test_fused_program_is_one_scan(ring_world):
     carry = ((exp.params, exp.opt_state) + exp._get_states() + (exp.rng,))
     jaxpr = jax.make_jaxpr(lambda c: fused(c))(carry)
     scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
-    pjits = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pjit"]
-    if pjits:  # the jitted program wraps the scan one level down
-        inner = pjits[0].params["jaxpr"].jaxpr
+    jits = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "jit"]
+    if jits:  # the jitted program wraps the scan one level down
+        inner = jits[0].params["jaxpr"].jaxpr
         scans = [e for e in inner.eqns if e.primitive.name == "scan"]
     assert len(scans) == 1
 
