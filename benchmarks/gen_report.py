@@ -533,7 +533,7 @@ def obs_section() -> str:
         out.append(f"Ledger summary: {s['rounds_per_sec']:.2f} rounds/s "
                    f"wall ({s['wall_s']:.1f}s"
                    + (f", cold compile {s['compile_s']:.1f}s"
-                      if "compile_s" in s else "") + ").")
+                      if s.get("cold_compile") else "") + ").")
     out.append("")
     return "\n".join(out)
 

@@ -101,6 +101,7 @@ from repro.comm import (DENSE_CTX, EdgeGossipTransport, PodContext,
 from repro.comm.trigger import edge_delivery
 from repro.dist.sharding import NODE_AXIS
 from repro.engine.neighborhood import DenseNeighborhood, SparseNeighborhood
+from repro.obs.spans import AGGREGATE, EXCHANGE, TRAIN
 from repro.timing import TimingState
 from repro.utils.pytree import tree_flatten_stacked
 
@@ -474,6 +475,7 @@ def _make_round_body(exp, *, loss_reduce):
         gradient_exchange = (_make_sparse_gradient_exchange(exp) if sparse
                              else _make_gradient_exchange(exp))
 
+    @jax.named_scope(AGGREGATE)
     def aggregate(rows, params, gathered, mask):
         state = (jax.tree.map(rows, agg_state) if caps.kind == "gossip"
                  else agg_state)
@@ -513,8 +515,9 @@ def _make_round_body(exp, *, loss_reduce):
             dt = cap = None
 
         # -- Alg. 1 l.4-9: local SGD (dead nodes run zero steps) -----------
-        params, opt, rng, train_loss, budgets_full = local_training(
-            params, opt, round_idx, rng, alive=alive, cap=cap)
+        with jax.named_scope(TRAIN):
+            params, opt, rng, train_loss, budgets_full = local_training(
+                params, opt, round_idx, rng, alive=alive, cap=cap)
         # realized per-node compute cost this round (0 for dead nodes)
         t_cost = (budgets_full.astype(jnp.float32) * dt if has_time
                   else None)
@@ -551,21 +554,26 @@ def _make_round_body(exp, *, loss_reduce):
                 arr_full = None
         old_params = params
 
-        def flat_gossip(params, gate_vec, table_mat=None, edge_mask=None,
+        @jax.named_scope(AGGREGATE)
+        def flat_gossip(params, gate_vec, table=None, edge_mask=None,
                         mask_full=None):
             """The flat-form gossip update: flatten the block's models,
             build the layout's Neighborhood over the full [N, D] table
-            (gathered here unless the transport already decoded one), and
-            run the strategy's flat aggregate.  `gate_vec` [N] {0,1} is the
-            senders' broadcast gate; `edge_mask` [E] {0,1} is the sparse
-            layout's per-edge factor (liveness ∩ arrival ∩ delivery
-            history); `mask_full` [N, max_deg] {0,1} is the dense layout's
-            fully-composed counterpart — when given it REPLACES the default
-            gate·link composition (the per-node transport computes its
-            silence semantics there)."""
+            (flattened from `table`, the transport's decoded models, or
+            gathered here without one), and run the strategy's flat
+            aggregate.  `gate_vec` [N] {0,1} is the senders' broadcast
+            gate; `edge_mask` [E] {0,1} is the sparse layout's per-edge
+            factor (liveness ∩ arrival ∩ delivery history); `mask_full`
+            [N, max_deg] {0,1} is the dense layout's fully-composed
+            counterpart — when given it REPLACES the default gate·link
+            composition (the per-node transport computes its silence
+            semantics there)."""
             local_mat, unflatten = tree_flatten_stacked(params)
-            if table_mat is None:
-                table_mat = ctx.gather(local_mat)
+            if table is not None:
+                table_mat = tree_flatten_stacked(table)[0]
+            else:
+                with jax.named_scope(EXCHANGE):
+                    table_mat = ctx.gather(local_mat)
             if sparse:
                 pod = ctx.pod if ctx.pod is not None else jnp.int32(0)
                 nb = SparseNeighborhood(plan, pod, table_mat, local_mat,
@@ -597,7 +605,8 @@ def _make_round_body(exp, *, loss_reduce):
                 # data-size weights intersected with liveness — an offline
                 # client's frozen params carry zero weight (the all-ones
                 # mask without dynamics is an exact no-op).
-                full = jax.tree.map(ctx.gather, params)
+                with jax.named_scope(EXCHANGE):
+                    full = jax.tree.map(ctx.gather, params)
                 params = aggregate(rows, params, full, alive)
             elif caps.kind == "gossip":
                 if use_flat:
@@ -606,8 +615,10 @@ def _make_round_body(exp, *, loss_reduce):
                         edge_mask=_and_masks(
                             ev.live if sparse and has_dyn else None, arr_e))
                 else:
-                    full = jax.tree.map(ctx.gather, params)
-                    gathered = strategy.exchange(exp, full, rows(nbr_idx))
+                    with jax.named_scope(EXCHANGE):
+                        full = jax.tree.map(ctx.gather, params)
+                        gathered = strategy.exchange(exp, full,
+                                                     rows(nbr_idx))
                     params = aggregate(rows, params, gathered,
                                        rows(link_full))
                 if caps.grad_exchange:
@@ -655,18 +666,21 @@ def _make_round_body(exp, *, loss_reduce):
                     # per-edge cache freezes and its bank serves the stale
                     # (or dropped) reconstruction, bit-identically.
                     link_e = link_e * arr_e
-                edge_table, mask_e, gate_full, new_comm = transport.exchange(
-                    params, comm_state, link_e, ck, live=live, reset=reset,
-                    ctx=ctx, wire=wire)
+                with jax.named_scope(EXCHANGE):
+                    edge_table, mask_e, gate_full, new_comm = \
+                        transport.exchange(params, comm_state, link_e, ck,
+                                           live=live, reset=reset, ctx=ctx,
+                                           wire=wire)
                 # participation/liveness/gates are already folded into the
                 # [E] masks, so the view gets no gate_vec/link_u of its own.
-                local_mat, unflatten = tree_flatten_stacked(params)
-                pod = ctx.pod if ctx.pod is not None else jnp.int32(0)
-                nb = SparseNeighborhood(
-                    plan, pod, None, local_mat, unflatten, None, None, 1.0,
-                    edge_table=edge_table, edge_mask=mask_e)
-                params = strategy.flat_aggregate(
-                    exp, jax.tree.map(rows, agg_state), nb)
+                with jax.named_scope(AGGREGATE):
+                    local_mat, unflatten = tree_flatten_stacked(params)
+                    pod = ctx.pod if ctx.pod is not None else jnp.int32(0)
+                    nb = SparseNeighborhood(
+                        plan, pod, None, local_mat, unflatten, None, None,
+                        1.0, edge_table=edge_table, edge_mask=mask_e)
+                    params = strategy.flat_aggregate(
+                        exp, jax.tree.map(rows, agg_state), nb)
                 if has_obs:
                     obs_fired = gate_full
                     obs_deliv = gate_full * link_e
@@ -677,25 +691,28 @@ def _make_round_body(exp, *, loss_reduce):
                     live = ev.live
                 else:
                     reset = live = None
-                gathered, mask, gate_full, new_comm = transport.exchange(
-                    params, comm_state, link_full, ck, live=live,
-                    reset=reset, ctx=ctx, wire=wire)
+                with jax.named_scope(EXCHANGE):
+                    gathered, mask, gate_full, new_comm = transport.exchange(
+                        params, comm_state, link_full, ck, live=live,
+                        reset=reset, ctx=ctx, wire=wire)
                 if use_flat:
                     # flat form over the transport's pre-gathered per-link
                     # panel (no single [N, D] table exists: slot models are
                     # per-link stale caches), composed weights ω·|D|·mask —
                     # the same kernel reduce as the per-node path, so
                     # fp32/thr0 stays bit-exact against it.
-                    local_mat, unflatten = tree_flatten_stacked(params)
-                    panel = jnp.concatenate(
-                        [l.reshape(l.shape[0], l.shape[1], -1)
-                          .astype(jnp.float32)
-                         for l in jax.tree.leaves(gathered)], axis=2)
-                    nb = DenseNeighborhood(None, None,
-                                           rows(nbr_weight) * mask,
-                                           local_mat, unflatten, panel=panel)
-                    params = strategy.flat_aggregate(
-                        exp, jax.tree.map(rows, agg_state), nb)
+                    with jax.named_scope(AGGREGATE):
+                        local_mat, unflatten = tree_flatten_stacked(params)
+                        panel = jnp.concatenate(
+                            [l.reshape(l.shape[0], l.shape[1], -1)
+                              .astype(jnp.float32)
+                             for l in jax.tree.leaves(gathered)], axis=2)
+                        nb = DenseNeighborhood(None, None,
+                                               rows(nbr_weight) * mask,
+                                               local_mat, unflatten,
+                                               panel=panel)
+                        params = strategy.flat_aggregate(
+                            exp, jax.tree.map(rows, agg_state), nb)
                 else:
                     params = aggregate(rows, params, gathered, mask)
                 if has_obs:
@@ -726,9 +743,10 @@ def _make_round_body(exp, *, loss_reduce):
                 send_mask = rows(ev.alive)
             else:
                 send_mask = None
-            decoded, gate_full, new_comm = transport.exchange(
-                params, comm_state, ck, send_mask=send_mask, ctx=ctx,
-                wire=wire)
+            with jax.named_scope(EXCHANGE):
+                decoded, gate_full, new_comm = transport.exchange(
+                    params, comm_state, ck, send_mask=send_mask, ctx=ctx,
+                    wire=wire)
             # `decoded` rows of silent nodes hold their cached last-sent
             # model, so "stale" aggregates them at full weight — masking
             # only edges that have NEVER DELIVERED, whose receiver-side
@@ -746,9 +764,10 @@ def _make_round_body(exp, *, loss_reduce):
                 cur_e = _and_masks(live_e, arr_e)
                 part_e = ((link_u < cfg.participation).astype(jnp.float32)
                           if link_u is not None else None)
-                delivered_e = _and_masks(gate_full[edge_src], part_e,
-                                         live_e, arr_e)
-                new_comm = transport.note_delivery(new_comm, delivered_e)
+                with jax.named_scope(EXCHANGE):
+                    delivered_e = _and_masks(gate_full[edge_src], part_e,
+                                             live_e, arr_e)
+                    new_comm = transport.note_delivery(new_comm, delivered_e)
                 if has_obs:
                     obs_fired = (gate_full[edge_src] * ev.live if has_dyn
                                  else gate_full[edge_src])
@@ -756,17 +775,19 @@ def _make_round_body(exp, *, loss_reduce):
                 if stale:
                     params = flat_gossip(
                         params, None,
-                        table_mat=tree_flatten_stacked(decoded)[0],
+                        table=decoded,
                         edge_mask=_and_masks(cur_e, new_comm.ever_recv))
                 else:
                     params = flat_gossip(
                         params, gate_full,
-                        table_mat=tree_flatten_stacked(decoded)[0],
+                        table=decoded,
                         edge_mask=cur_e)
             else:
-                delivered_full = edge_delivery(gate_full, link_full,
-                                               nbr_idx)
-                new_comm = transport.note_delivery(new_comm, delivered_full)
+                with jax.named_scope(EXCHANGE):
+                    delivered_full = edge_delivery(gate_full, link_full,
+                                                   nbr_idx)
+                    new_comm = transport.note_delivery(new_comm,
+                                                       delivered_full)
                 if has_obs:
                     obs_fired = gate_full[nbr_idx] * (ev.live if has_dyn
                                                       else nbr_valid)
@@ -778,11 +799,12 @@ def _make_round_body(exp, *, loss_reduce):
                 if use_flat:
                     params = flat_gossip(
                         params, None,
-                        table_mat=tree_flatten_stacked(decoded)[0],
+                        table=decoded,
                         mask_full=mask_full)
                 else:
-                    gathered = strategy.exchange(exp, decoded,
-                                                 rows(nbr_idx))
+                    with jax.named_scope(EXCHANGE):
+                        gathered = strategy.exchange(exp, decoded,
+                                                     rows(nbr_idx))
                     params = aggregate(rows, params, gathered,
                                        rows(mask_full))
             # broadcast accounting: a transmitting node pays one payload

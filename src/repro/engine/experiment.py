@@ -63,7 +63,7 @@ from repro.graphs.sparse import SparseTopology
 from repro.graphs.topology import Topology
 from repro.models.api import SmallModel
 from repro.obs import (RunLedger, Telemetry, log_round, round_record,
-                       run_manifest)
+                       run_manifest, spans)
 from repro.optim.sgd import sgd_momentum
 from repro.timing import Timing
 from repro.utils.pytree import tree_flatten_stacked
@@ -334,7 +334,7 @@ class Experiment:
                          batch_size=min(train.eval_batch, len(world.x_test))),
             in_axes=(0, None, None),
         )
-        self._eval = jax.jit(self._eval_raw)
+        self._eval = jax.jit(jax.named_scope(spans.EVAL)(self._eval_raw))
 
         # --- init (heterogeneous unless the method coordinates) ---
         base = jax.random.PRNGKey(train.seed)
@@ -473,6 +473,8 @@ class Experiment:
         donate = tuple(range(2 + sum(self._state_flags())))
         self._round = jax.jit(self._round_raw, donate_argnums=donate)
         self._fused_cache = {}
+        # what the compile counters moved in the last compile()
+        self.compile_stats: Optional[Dict[str, float]] = None
 
     # ------------------------------------------------------------------
     def evaluate(self) -> RoundMetrics:
@@ -538,6 +540,7 @@ class Experiment:
         x_test, y_test, n = self.x_test, self.y_test, self.n
         n_states = sum(self._state_flags())
 
+        @jax.named_scope(spans.EVAL)
         def _eval_on(p):
             acc, loss = eval_fn(p, x_test, y_test)
             return acc, loss, (probes_fn(p) if probes_fn is not None
@@ -657,51 +660,52 @@ class Experiment:
         donation honored) — and keep the executable for every later
         `run()` of that schedule.  Returns it (`.as_text()` is the
         optimized HLO); compiling outside `run()` separates the compile
-        seconds from the dispatch."""
+        seconds from the dispatch.  `compile_stats` keeps what the compile
+        counters (`repro.obs.spans`) moved in this call: lowering seconds
+        apart from the backend's load or compile."""
         rounds = self.schedule.rounds if rounds is None else rounds
         eval_every = (self.schedule.eval_every if eval_every is None
                       else eval_every)
         fused = self._fused_program(rounds, eval_every)
-        if isinstance(fused, jax.stages.Compiled):
-            return fused
-        compiled = fused.lower(self._carry()).compile()
-        self._fused_cache[(rounds, eval_every)] = compiled
-        return compiled
+        before = spans.counters()
+        if not isinstance(fused, jax.stages.Compiled):
+            with spans.span("dfl.compile.lower"):
+                lowered = fused.lower(self._carry())
+            with spans.span("dfl.compile.load"):
+                fused = lowered.compile()
+            self._fused_cache[(rounds, eval_every)] = fused
+        self.compile_stats = spans.counter_diff(spans.counters(), before)
+        return fused
 
     def _run_fused(self, rounds, eval_every, verbose) -> List[RoundMetrics]:
-        cold = (rounds, eval_every) not in self._fused_cache
         n_states = sum(self._state_flags())
-        if self.ledger is not None and cold:
-            # compile-time counter for the ledger
-            t0 = _time.perf_counter()
-            self.compile(rounds, eval_every)
-            self._compile_s = _time.perf_counter() - t0
         fused = self._fused_program(rounds, eval_every)
-        carry = self._carry()
-        self._cold_compile = cold
-        carry, ys = fused(carry)
+        with spans.span("dfl.run.dispatch"):
+            carry, ys = fused(self._carry())
         self.params, self.opt_state = carry[:2]
         self._set_states(carry[2:2 + n_states])
         self.rng = carry[-1]
-        acc_r, loss_r = np.asarray(ys[0]), np.asarray(ys[1])
-        # the telemetry extras group is a DICT of stacked arrays — convert
-        # per leaf (scalars and dicts alike) rather than per group
-        extras_r = [jax.tree.map(np.asarray, e) for e in ys[2:]]
+        with spans.span("dfl.run.fetch"):
+            acc_r, loss_r = np.asarray(ys[0]), np.asarray(ys[1])
+            # the telemetry extras group is a DICT of stacked arrays —
+            # convert per leaf (scalars and dicts alike), not per group
+            extras_r = [jax.tree.map(np.asarray, e) for e in ys[2:]]
         # the eval-gated params probes ride as the LAST scan output, after
         # the round extras (zeros on non-eval rounds — never read there)
         probes_r = extras_r.pop() if self._probes_raw is not None else None
 
         evals = set(Schedule.eval_rounds(rounds, eval_every))
         history: List[RoundMetrics] = []
-        for r in range(rounds):
-            self._account_extras(
-                [jax.tree.map(lambda a: a[r], e) for e in extras_r])
-            if r in evals:
-                m = RoundMetrics(round=r, acc_per_node=acc_r[r],
-                                 loss_per_node=loss_r[r])
-                probes = (jax.tree.map(lambda a: a[r], probes_r)
-                          if probes_r is not None else None)
-                self._finish_metrics(m, history, verbose, probes=probes)
+        with spans.span("dfl.run.account"):
+            for r in range(rounds):
+                self._account_extras(
+                    [jax.tree.map(lambda a: a[r], e) for e in extras_r])
+                if r in evals:
+                    m = RoundMetrics(round=r, acc_per_node=acc_r[r],
+                                     loss_per_node=loss_r[r])
+                    probes = (jax.tree.map(lambda a: a[r], probes_r)
+                              if probes_r is not None else None)
+                    self._finish_metrics(m, history, verbose, probes=probes)
         return history
 
     def _run_loop(self, rounds, eval_every, verbose) -> List[RoundMetrics]:
@@ -745,25 +749,28 @@ class Experiment:
         if mode not in SCHEDULE_MODES:
             raise ValueError(f"schedule mode must be one of {SCHEDULE_MODES}, "
                              f"got {mode!r}")
-        self._cold_compile = None
-        self._compile_s = None
         profile = contextlib.nullcontext()
         if self.telemetry is not None and self.telemetry.profile_dir:
             profile = jax.profiler.trace(self.telemetry.profile_dir)
+        before = spans.counters()
         t0 = _time.perf_counter()
-        with profile:
+        with profile, spans.span("dfl.run"):
             if mode == "fused":
                 history = self._run_fused(rounds, eval_every, verbose)
             else:
                 history = self._run_loop(rounds, eval_every, verbose)
+        wall = _time.perf_counter() - t0
         if self.ledger is not None:
-            wall = _time.perf_counter() - t0
-            rec = {"kind": "summary", "mode": mode, "rounds": int(rounds),
-                   "wall_s": wall,
-                   "rounds_per_sec": rounds / max(wall, 1e-9)}
-            if self._cold_compile is not None:
-                rec["cold_compile"] = bool(self._cold_compile)
-            if self._compile_s is not None:
-                rec["compile_s"] = self._compile_s
-            self.ledger.write(rec)
+            stats = spans.counter_diff(spans.counters(), before)
+            requests, hits = (int(stats["compile_requests"]),
+                              int(stats["cache_hits"]))
+            self.ledger.write({
+                "kind": "summary", "mode": mode, "rounds": int(rounds),
+                "wall_s": wall, "rounds_per_sec": rounds / max(wall, 1e-9),
+                # a persistent-cache load is a request but not a compile
+                "cold_compile": requests > hits,
+                "compile_s": stats["lower_s"] + stats["load_s"],
+                "lower_s": stats["lower_s"], "load_s": stats["load_s"],
+                "compile_requests": requests, "cache_hits": hits,
+                "cache_retrieval_s": stats["cache_retrieval_s"]})
         return history

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.ops import segment_neighbor_avg
+from repro.obs.spans import REDUCE
 
 
 class WidthBucket(NamedTuple):
@@ -145,9 +146,11 @@ class DenseNeighborhood:
     def local(self):
         return self.local_mat
 
+    @jax.named_scope(REDUCE)
     def reduce(self):
         return segment_neighbor_avg(self._vals(), self.w)
 
+    @jax.named_scope(REDUCE)
     def reduce_delta(self):
         vals = self._vals() - self.local_mat[:, None, :]
         return segment_neighbor_avg(vals, self.w)
@@ -219,6 +222,7 @@ class SparseNeighborhood:
     def local(self):
         return self.local_mat
 
+    @jax.named_scope(REDUCE)
     def _reduce(self, delta: bool):
         r, d = self.local_mat.shape
         sums = jnp.zeros((r + 1, d), jnp.float32)

@@ -1,5 +1,5 @@
 """repro.obs — scan-native observability: telemetry channels, the run
-ledger, and event-clock trace export.
+ledger, event-clock trace export, and the program's own performance spans.
 
     from repro.obs import Telemetry
     world = World.synthetic(nodes=16, telemetry=Telemetry(
@@ -15,6 +15,9 @@ one `lax.scan` carry (no host syncs mid-run, no rng consumed), and
 pinned across backends × layouts × schedule modes in tests/test_obs.py.
 See docs/observability.md for the channel catalog, the ledger schema, and
 a trace-export worked example.
+
+`repro.obs.spans` is always on: named device scopes for the round's
+phases, host spans on the profiler's clock, and compile counters.
 """
 from repro.obs.channels import (  # noqa: F401
     CHANNELS,
@@ -37,5 +40,13 @@ from repro.obs.ledger import (  # noqa: F401
     run_manifest,
     validate_ledger,
     validate_record,
+)
+from repro.obs.spans import (  # noqa: F401
+    SCOPES,
+    compile_times,
+    counter_diff,
+    counters,
+    span,
+    span_table,
 )
 from repro.obs.trace import build_trace, export_trace  # noqa: F401

@@ -10,9 +10,12 @@ Three record kinds, one JSON object per line:
     channels can be joined back to the graph;
   * ``round``    — one per eval round: the full RoundMetrics surface
     (per-node accuracy included) plus the materialized channel `detail`;
-  * ``summary``  — one per `run()` call: wall seconds, rounds/sec, and the
-    compile-time counters (cold compile + lowering/compile seconds for the
-    fused program).
+  * ``summary``  — one per `run()` call: wall seconds, rounds/sec, and
+    what the compile counters (`repro.obs.spans`) moved during the call:
+    lowering and load seconds, compile requests, persistent-cache hits and
+    the seconds spent reading them;
+    `cold_compile` is true iff a request was a real compile, and
+    `compile_s` is lowering + load.
 
 Validation is hand-rolled against `SCHEMA` (stdlib-only — no jsonschema
 dependency): required fields with type checks per kind, unknown kinds
@@ -73,6 +76,9 @@ SCHEMA = {
         },
         "optional": {
             "cold_compile": bool, "compile_s": (int, float),
+            "lower_s": (int, float), "load_s": (int, float),
+            "compile_requests": int, "cache_hits": int,
+            "cache_retrieval_s": (int, float),
         },
     },
 }

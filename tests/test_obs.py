@@ -21,10 +21,16 @@ The load-bearing pins:
   5. ledger/trace — the JSONL ledger round-trips through its schema
      validator with the manifest first, the verbose console line is
      byte-stable against the pre-ledger format, and the exported Chrome
-     trace's per-edge transfer spans sum EXACTLY to bytes_on_wire.
+     trace's per-edge transfer spans sum EXACTLY to bytes_on_wire;
+  6. performance spans — the compiled round carries the five `dfl.*`
+     named scopes on every layout and transport, compile() counts its
+     lowering and its one compile request, a warm run() counts none, and
+     a channel-free profile binds no telemetry state.
 """
 import dataclasses
+import glob
 import json
+import re
 
 import jax
 import numpy as np
@@ -34,10 +40,10 @@ from repro.comm import CommConfig
 from repro.engine import Experiment, Schedule, World
 from repro.fl.metrics import (RoundMetrics, accuracy_table,
                               characteristic_time)
-from repro.obs import (CHANNELS, Telemetry, available_channels,
-                       build_trace, channels_for, export_trace,
-                       format_round, read_ledger, validate_ledger,
-                       validate_record)
+from repro.obs import (CHANNELS, SCOPES, Telemetry, available_channels,
+                       build_trace, channels_for, counter_diff, counters,
+                       export_trace, format_round, read_ledger, span,
+                       span_table, validate_ledger, validate_record)
 from repro.timing import LognormalLink, LognormalStep, Timing
 
 TINY = dict(steps_per_round=4, batch_size=16, lr=0.1, momentum=0.9, seed=3)
@@ -312,6 +318,18 @@ def test_ledger_round_trip(ring_world, tmp_path):
     assert summary["wall_s"] > 0
     assert summary["rounds_per_sec"] > 0
     assert "compile_s" in summary  # fresh experiment: cold compile
+    # the fresh program's one request, a compile unless the persistent
+    # cache served it
+    assert summary["compile_requests"] >= 1
+    assert summary["cold_compile"] == (summary["compile_requests"]
+                                       > summary["cache_hits"])
+    assert summary["compile_s"] == pytest.approx(summary["lower_s"]
+                                                 + summary["load_s"])
+    assert summary["lower_s"] > 0
+    # seconds spent reading the persistent cache: none without a hit
+    assert summary["cache_retrieval_s"] >= 0
+    if summary["cache_hits"] == 0:
+        assert summary["cache_retrieval_s"] == 0
 
 
 def test_validate_record_rejects_garbage():
@@ -396,6 +414,117 @@ def test_trace_requires_timing_and_telemetry(ring_world):
                    schedule=Schedule(rounds=1, eval_every=1))
     with pytest.raises(ValueError, match="timing"):
         build_trace(exp2)
+
+
+# --------------------------------------------- 7. performance spans
+
+def _hlo_scopes(exp, rounds=2):
+    """{innermost dfl.* scope} over the op_name metadata of the compiled
+    fused program."""
+    hlo = exp.compile(rounds, rounds).as_text()
+    found = set()
+    for meta in re.findall(r'op_name="([^"]*)"', hlo):
+        inner = [c for c in meta.split("/") if c in SCOPES]
+        if inner:
+            found.add(inner[-1])
+    return found
+
+
+@pytest.mark.parametrize("layout, comm, want", [
+    # the paper's path: per-node fp32 gossip, dense, vmap, DecDiff+VT
+    ("dense", CommConfig(codec="fp32"), set(SCOPES)),
+    ("sparse", CommConfig(codec="fp32"), {"dfl.reduce", "dfl.exchange"}),
+    ("dense", CommConfig(codec="int8", per_edge=True),
+     {"dfl.reduce", "dfl.exchange"}),
+    ("sparse", CommConfig(codec="int8", per_edge=True),
+     {"dfl.reduce", "dfl.exchange"}),
+])
+def test_round_phases_are_named_in_the_compiled_program(ring_world, layout,
+                                                        comm, want):
+    exp = Experiment(ring_world, "decdiff+vt", comm=comm, layout=layout,
+                     schedule=Schedule(rounds=2, eval_every=2), **TINY)
+    assert want <= _hlo_scopes(exp)
+
+
+def test_compile_counts_one_request_and_a_warm_run_none(ring_world):
+    exp = Experiment(ring_world, "decdiff+vt", comm=CommConfig(codec="fp32"),
+                     schedule=Schedule(rounds=2, eval_every=2), **TINY)
+    exp.compile(2, 2)
+    assert exp.compile_stats["lower_s"] > 0
+    assert exp.compile_stats["load_s"] > 0
+    assert exp.compile_stats["compile_requests"] == 1
+    before = span_table()
+    for _ in range(2):
+        moved = counters()
+        exp.run(rounds=2, eval_every=2)
+        moved = counter_diff(counters(), moved)
+        assert moved["compile_requests"] == 0
+        assert moved["lower_s"] == 0
+    after = span_table()
+    for name in ("dfl.run", "dfl.run.dispatch", "dfl.run.fetch",
+                 "dfl.run.account"):
+        assert after[name]["count"] - before.get(
+            name, {"count": 0})["count"] == 2
+    assert after["dfl.compile.lower"]["lower_s"] > 0
+    assert after["dfl.compile.load"]["compile_requests"] >= 1
+
+
+def test_span_table_keeps_the_compiles_inside_each_span():
+    before = counters()
+    outer0 = span_table().get("test.outer", {"count": 0,
+                                             "compile_requests": 0})
+    with span("test.outer"):
+        with span("test.inner"):
+            # a function JAX has never seen compiles once
+            jax.jit(lambda x: x * 3.0 + 1.0)(np.float32(2.0))
+    moved = counter_diff(counters(), before)
+    assert moved["compile_requests"] == 1
+    assert moved["lower_s"] > 0
+    table = span_table()
+    assert table["test.outer"]["count"] == outer0["count"] + 1
+    assert (table["test.outer"]["compile_requests"]
+            - outer0["compile_requests"]) == 1
+    assert table["test.inner"]["compile_requests"] >= 1
+    assert table["test.outer"]["seconds"] >= table["test.inner"]["seconds"]
+
+
+def test_nested_lowering_counts_once():
+    """A jitted callee is traced inside its caller's trace: its seconds
+    are already in the caller's and count once."""
+    import time
+
+    @jax.jit
+    def inner(x):
+        time.sleep(0.5)  # runs while tracing
+        return x * 2.0
+
+    @jax.jit
+    def outer(x):
+        return inner(x) + 1.0
+
+    before = counters()
+    t0 = time.perf_counter()
+    outer.lower(np.float32(1.0))
+    wall = time.perf_counter() - t0
+    lowered = counter_diff(counters(), before)["lower_s"]
+    assert 0.5 <= lowered <= wall
+
+
+def test_profile_without_channels_binds_no_telemetry_state(ring_world,
+                                                           tmp_path):
+    """`Telemetry(channels=(), profile_dir=...)` profiles the program that
+    runs untelemetered: no channel state in the carry, the same params."""
+    runs = {}
+    for tele in (None, Telemetry(channels=(), profile_dir=str(tmp_path))):
+        exp, _ = _run(_with(ring_world, telemetry=tele),
+                      comm=CommConfig(codec="fp32"),
+                      schedule=Schedule(rounds=2, eval_every=2))
+        runs[tele is None] = exp
+    prof, plain = runs[False], runs[True]
+    assert prof.bound_obs is None and prof.obs_state is None
+    assert len(prof._get_states()) == len(plain._get_states())
+    assert _params_equal(prof.params, plain.params)
+    assert glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
 
 
 # ------------------------------------------------- 8. metrics edge cases
