@@ -19,11 +19,16 @@ Two implementations share those semantics bit-for-bit:
     O(N + E) state instead of O(N·max_deg).
 
 Both evaluate every per-receiver contraction through
-`repro.kernels.ops.segment_neighbor_avg`, whose kernel contracts each
-receiver row independently — so the reduce is bitwise invariant to row
-blocking (vmap's R=N vs a pod's R=N/P) and to K-width zero padding (the
-dense max_deg slots vs a sparse bucket's power-of-two width).  Totals ride
-the contraction as a ones column (a separate `jnp.sum(w)` would not be
+`repro.kernels.ops.segment_neighbor_avg_rows`: its input is the `[M, D]`
+table itself (the decoded models, or the sparse per-edge bank) with
+`[B, K]` row ids, and its kernel gathers each receiver's rows from HBM and
+contracts each receiver row independently — so the reduce is bitwise
+invariant to row blocking (vmap's R=N vs a pod's R=N/P) and to K-width
+zero padding (the dense max_deg slots vs a sparse bucket's power-of-two
+width).  Only the per-edge transport's per-link reconstructions and the
+delta forms (x_k - local) exist as a `[R, K, D]` panel; they go through
+`segment_neighbor_avg`, the same kernel over the flattened panel.  Totals
+ride the contraction (a separate `jnp.sum(w)` would not be
 width-invariant), and normalization happens AFTER the reduce, on per-row
 scalars, in the strategy's `flat_aggregate`.
 """
@@ -35,7 +40,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.ops import segment_neighbor_avg
+from repro.kernels.ops import (
+    segment_neighbor_avg,
+    segment_neighbor_avg_rows,
+)
 from repro.obs.spans import REDUCE
 
 
@@ -148,7 +156,9 @@ class DenseNeighborhood:
 
     @jax.named_scope(REDUCE)
     def reduce(self):
-        return segment_neighbor_avg(self._vals(), self.w)
+        if self.panel is not None:
+            return segment_neighbor_avg(self.panel, self.w)
+        return segment_neighbor_avg_rows(self.table, self.nbr_idx, self.w)
 
     @jax.named_scope(REDUCE)
     def reduce_delta(self):
@@ -189,7 +199,7 @@ class SparseNeighborhood:
         its `[N, max_deg]` mask panel.
 
     Padding slots point at edge 0 (finite garbage) with wgt = 0, which the
-    `segment_neighbor_avg` kernel contract makes bit-neutral."""
+    `segment_neighbor_avg_rows` kernel contract makes bit-neutral."""
 
     def __init__(self, plan: SparsePlan, pod, table, local_mat, unflatten_fn,
                  gate_vec, link_u, participation: float, *,
@@ -234,12 +244,15 @@ class SparseNeighborhood:
             rows_local = self._take(bk.rows_local)
             src = self._take(bk.src)
             epos = self._take(bk.epos)
-            vals = (self.edge_table[epos] if self.edge_table is not None
-                    else self.table[src])
-            if delta:
-                vals = vals - local_pad[rows_local][:, None, :]
+            table, idx = ((self.edge_table, epos)
+                          if self.edge_table is not None
+                          else (self.table, src))
             w = self._weights(src, self._take(bk.wgt), epos)
-            s, t = segment_neighbor_avg(vals, w)
+            if delta:
+                vals = table[idx] - local_pad[rows_local][:, None, :]
+                s, t = segment_neighbor_avg(vals, w)
+            else:
+                s, t = segment_neighbor_avg_rows(table, idx, w)
             sums = sums.at[rows_local].set(s)
             tot = tot.at[rows_local].set(t)
         return sums[:r], tot[:r]

@@ -188,41 +188,51 @@ def dequant_neighbor_avg(q, scales, weights, interpret=None):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def segment_neighbor_avg(vals, w, interpret=None):
-    """Ragged neighbor reduce: per-receiver (Σ_k w·vals, Σ_k w) in one pass.
+def segment_neighbor_avg_rows(table, idx, w, interpret=None):
+    """Ragged neighbor reduce over a table: per-receiver (Σ_k w·table[idx],
+    Σ_k w) in one kernel pass that gathers the rows itself.
 
-    vals [B, K, D] f32 slot-padded neighbour rows (src-ascending per row,
-    garbage allowed wherever w is 0), w [B, K] f32 unnormalized gossip
-    weights (0 at padding/undelivered slots) -> (sums [B, D], tot [B]).
+    table [M, D] f32 rows (decoded models, a per-edge bank), idx [B, K]
+    int32 row ids (any valid row wherever w is 0), w [B, K] f32
+    unnormalized gossip weights (0 at padding/undelivered slots) ->
+    (sums [B, D], tot [B]).
 
-    A ones column rides along as column D so the totals come out of the
-    same per-row contraction as the sums (a separate `jnp.sum(w)` would
-    not be bitwise K-width-invariant).  Each receiver row is contracted
-    independently inside the kernel (see `repro.kernels.segment_avg`), and
-    `lax.map` drives fixed ROWS-row chunks so the kernel traces once: the
-    result is bitwise invariant to B, chunking, and K zero-padding — the
-    dense engine at small N is therefore an exact oracle for this path.
-    K is zero-padded to a multiple of 8 so the kernel's dot always has a
-    sublane-aligned contraction width, whatever the graph's degrees.
+    The totals come out of the same per-row contraction as the sums (a
+    separate `jnp.sum(w)` would not be bitwise K-width-invariant), and
+    zero-weight slots are never fetched.  Each receiver row is contracted
+    independently inside the kernel (see `repro.kernels.segment_avg`): the
+    result is bitwise invariant to B, row blocking, and K zero-padding —
+    the dense engine at small N is therefore an exact oracle for this
+    path.  K is zero-padded to a multiple of 8 so the kernel's dot always
+    has a sublane-aligned contraction width, whatever the graph's degrees.
     """
     from repro.kernels import segment_avg as _sa
 
     interpret = _interpret_default() if interpret is None else interpret
+    d = table.shape[1]
+    b, k = idx.shape
+    pad = ((0, (-b) % _sa.ROWS), (0, (-k) % _sa.K_ALIGN))
+    wp = jnp.pad(w.astype(jnp.float32), pad)
+    ip = jnp.where(wp != 0, jnp.pad(idx.astype(jnp.int32), pad), -1)
+    cols = _sa.gather_cols(d, wp.shape[1], interpret)
+    sums, tot = _sa.segment_avg_gather(
+        ip, wp, table.astype(jnp.float32)[:, None, :], cols=cols,
+        interpret=interpret)
+    return sums[:b], tot[:b, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def segment_neighbor_avg(vals, w, interpret=None):
+    """The panel form: vals [B, K, D] f32 slot-padded neighbour rows
+    (garbage allowed wherever w is 0), w [B, K] -> (sums [B, D], tot [B]).
+
+    The per-edge transport's reconstructions and the delta forms
+    (x_k - local) exist only as a panel; slot k of receiver b is row
+    b·K + k of the flattened panel, through the one table-form kernel."""
     b, k, d = vals.shape
-    v2 = jnp.concatenate([vals.astype(jnp.float32),
-                          jnp.ones((b, k, 1), jnp.float32)], axis=2)
-    pk = (-k) % _sa.K_ALIGN
-    v2 = jnp.pad(v2, ((0, (-b) % _sa.ROWS), (0, pk),
-                      (0, (-(d + 1)) % _sa.COLS)))
-    wp = jnp.pad(w.astype(jnp.float32), ((0, (-b) % _sa.ROWS), (0, pk)))
-    bp, k, dp = v2.shape
-    out = jax.lax.map(
-        lambda args: _sa.segment_avg_chunk(args[0], args[1],
-                                           interpret=interpret),
-        (wp.reshape(bp // _sa.ROWS, _sa.ROWS, k),
-         v2.reshape(bp // _sa.ROWS, _sa.ROWS, k, dp)))
-    out = out.reshape(bp, dp)[:b]
-    return out[:, :d], out[:, d]
+    idx = jnp.arange(b * k, dtype=jnp.int32).reshape(b, k)
+    return segment_neighbor_avg_rows(vals.reshape(b * k, d), idx, w,
+                                     interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -47,7 +47,7 @@ import jax
 
 TRAIN = "dfl.train"          # local training: batches, fwd, bwd, optimizer
 EXCHANGE = "dfl.exchange"    # trigger, encode, wire, decode, delivery
-REDUCE = "dfl.reduce"        # neighbour gather, pad, segment_avg kernel
+REDUCE = "dfl.reduce"        # table view, segment_avg gather kernel
 AGGREGATE = "dfl.aggregate"  # flatten, weights, the update, unflatten
 EVAL = "dfl.eval"            # the eval pass and its params probes
 SCOPES = (TRAIN, EXCHANGE, REDUCE, AGGREGATE, EVAL)

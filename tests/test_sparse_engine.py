@@ -9,9 +9,11 @@ The acceptance pins for `Experiment(layout="sparse")`:
   2. backends — the sparse layout lowers to shard_map bit-identically to
      vmap (single-pod here, the forced 4-device mesh in the multihost
      lane);
-  3. kernels — `segment_neighbor_avg` is bitwise invariant to row
-     blocking, K zero-padding (finite garbage under zero weight), and
-     feature-column tiling: the properties the oracle equality rests on;
+  3. kernels — `segment_neighbor_avg_rows` (the table form, which gathers
+     neighbour rows inside the kernel) equals the panel form bit for bit
+     and is bitwise invariant to row blocking, K zero-padding (any row or
+     finite garbage under zero weight), and feature-column tiling: the
+     properties the oracle equality rests on;
   4. plan — `build_sparse_plan` lays every node out exactly once, in the
      contiguous pod blocks shard_map slices, with the same ω·|D_src|
      weight product as the dense layout;
@@ -50,6 +52,7 @@ from repro.kernels import segment_avg as _sa
 from repro.kernels.ops import (
     dequant_segment_neighbor_avg,
     segment_neighbor_avg,
+    segment_neighbor_avg_rows,
 )
 
 
@@ -180,12 +183,77 @@ def _rows_ref(w, v):
         for r in range(w.shape[0])])
 
 
-def test_segment_avg_chunk_bitwise_per_row():
+def _gather_inputs(rng, m, b, k, d, live=0.7):
+    """A [m, d] table, [b, k] row ids (repeats allowed) and weights with a
+    share of zero-weight slots."""
+    table = jnp.asarray(rng.normal(size=(m, d)).astype(np.float32))
+    idx = jnp.asarray(rng.integers(0, m, size=(b, k)).astype(np.int32))
+    w = jnp.asarray((rng.random((b, k)) < live).astype(np.float32)
+                    * rng.uniform(0.5, 2.0, (b, k)).astype(np.float32))
+    return table, idx, w
+
+
+@pytest.mark.parametrize("cols", [None, 128])
+def test_segment_avg_gather_bitwise_per_row(cols):
+    """The gather kernel against the per-row einsum contract, in one
+    full-width column tile and in 128-wide tiles with a partial last one
+    (d = 300): column tiling cannot move a bit."""
     rng = np.random.default_rng(0)
-    w = jnp.asarray(rng.normal(size=(_sa.ROWS, 8)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(_sa.ROWS, 8, 256)).astype(np.float32))
-    out = _sa.segment_avg_chunk(w, v)
-    assert np.array_equal(np.asarray(out), np.asarray(_rows_ref(w, v)))
+    m, b, k, d = 12, _sa.ROWS * 2, 8, 300
+    table, idx, w = _gather_inputs(rng, m, b, k, d)
+    ip = jnp.where(w != 0, idx, -1)
+    sums, tot = _sa.segment_avg_gather(ip, w, table[:, None, :],
+                                       cols=cols or d)
+    assert np.array_equal(np.asarray(sums),
+                          np.asarray(_rows_ref(w, table[idx])))
+    ones = jnp.ones((b, k, _sa.LANES), jnp.float32)
+    assert np.array_equal(np.asarray(tot), np.asarray(_rows_ref(w, ones)))
+
+
+@pytest.mark.parametrize("k", [13, 17])
+def test_segment_neighbor_avg_rows_k_not_multiple_of_8(k):
+    """Degree widths that are not multiples of 8 (the dense max_deg of
+    other seeds and graphs) are zero-padded without moving a bit."""
+    rng = np.random.default_rng(10 + k)
+    table, idx, w = _gather_inputs(rng, 20, 11, k, 150)
+    sums, tot = segment_neighbor_avg_rows(table, idx, w)
+    assert np.array_equal(np.asarray(sums),
+                          np.asarray(_rows_ref(w, table[idx])))
+    assert np.array_equal(np.asarray(tot), np.asarray(_rows_ref(
+        w, jnp.ones((11, k, 1), jnp.float32))[:, 0]))
+
+
+@pytest.mark.parametrize("repeats", [False, True])
+def test_segment_neighbor_avg_rows_equals_panel_form(repeats):
+    """The table form equals the panel form over `table[idx]` bit for bit,
+    also when receivers share rows or name one row in several slots."""
+    rng = np.random.default_rng(11)
+    table, idx, w = _gather_inputs(rng, 6 if repeats else 40, 21, 16, 97)
+    if repeats:
+        idx = idx.at[:, 1].set(idx[:, 0])
+    sums, tot = segment_neighbor_avg_rows(table, idx, w)
+    sums_p, tot_p = segment_neighbor_avg(table[idx], w)
+    assert np.array_equal(np.asarray(sums), np.asarray(sums_p))
+    assert np.array_equal(np.asarray(tot), np.asarray(tot_p))
+
+
+@pytest.mark.parametrize("pad_row", [0, 7, 30, 31])
+def test_segment_neighbor_avg_rows_zero_weight_slots_bit_neutral(pad_row):
+    """Zero-weight slots are bit-neutral wherever their ids point: at any
+    valid row (the dense layout's padding, an undelivered edge) or at rows
+    of finite garbage (30, 31 hold ±3.4e38)."""
+    rng = np.random.default_rng(12)
+    m, b, k, d = 30, 9, 5, 64
+    table, idx, w = _gather_inputs(rng, m, b, k, d, live=1.0)
+    sums, tot = segment_neighbor_avg_rows(table, idx, w)
+    garbage = jnp.concatenate([table, jnp.full((1, d), 3.4e38),
+                               jnp.full((1, d), -3.4e38)])
+    idx_pad = jnp.concatenate(
+        [idx, jnp.full((b, 11), pad_row, jnp.int32)], axis=1)
+    w_pad = jnp.concatenate([w, jnp.zeros((b, 11), jnp.float32)], axis=1)
+    sums_p, tot_p = segment_neighbor_avg_rows(garbage, idx_pad, w_pad)
+    assert np.array_equal(np.asarray(sums), np.asarray(sums_p))
+    assert np.array_equal(np.asarray(tot), np.asarray(tot_p))
 
 
 def test_dequant_segment_avg_chunk_bitwise_per_row():
