@@ -30,34 +30,39 @@ ROOT = os.path.dirname(HERE)
 
 def readings(workload, seeds, program=True,
              variants=("bf16", "half_batch", "no_exchange"), overrides=None,
-             emit=print):
+             emit=print, where=None):
     sys.path.insert(0, ROOT)
     sys.path.insert(0, os.path.join(ROOT, "src"))
-    import jax
     import jax.numpy as jnp
 
     from bench import correct, drive
-    from bench.reference import reference_run
-    from bench.run import cache_setup, cell_spec
-    from bench.world import build_world
+    from bench.reference import node_sharding, reference_run
+    from bench.run import cache_setup, cell_spec, make_world
     from repro.utils.compile_cache import enable_compile_cache
 
     cache_setup(enable_compile_cache)
-    spec = cell_spec(workload, overrides)
+    spec = cell_spec(workload, overrides, where)
     cfg, traffic, model = spec["cfg"], spec["traffic"], spec["model"]
-    world = build_world(cfg["world"])
+    chips = spec["cell"]["chips"]
+    world = make_world(spec)
     n, rounds = world.num_nodes, traffic["rounds_per_call"]
     calls = traffic["set_up_calls"]
+
+    def ref_params(seed):
+        return drive.make_params(model, cfg, seed, n,
+                                 sharding=node_sharding(chips))
+
     out = []
     for seed in seeds:
-        ref = reference_run(model, cfg, world,
-                            drive.make_params(model, cfg, seed, n), rounds,
-                            calls)
+        ref = reference_run(model, cfg, world, ref_params(seed), rounds,
+                            calls, chips=chips)
         found = {}
         if program:
+            place = drive.placement(traffic, chips)
             exp = drive.build_experiment(
                 model, cfg, traffic, world,
-                drive.make_params(model, cfg, seed, n), seed)
+                drive.make_params(model, cfg, seed, n, sharding=place),
+                seed, place)
             exp.compile(rounds, rounds)
             prog = drive.set_up_calls(exp, rounds, calls)
             prog.pop("bytes")
@@ -67,9 +72,8 @@ def readings(workload, seeds, program=True,
         for v in variants:
             dtype = jnp.bfloat16 if v == "bf16" else jnp.float32
             fault = None if v == "bf16" else v
-            got = reference_run(model, cfg, world,
-                                drive.make_params(model, cfg, seed, n),
-                                rounds, calls, dtype=dtype, fault=fault)
+            got = reference_run(model, cfg, world, ref_params(seed), rounds,
+                                calls, dtype=dtype, fault=fault, chips=chips)
             found[v] = correct.gaps(got, ref)
         for k, g in found.items():
             line = {"workload": workload, "seed": seed, "variant": k,

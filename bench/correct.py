@@ -52,8 +52,8 @@ def gaps(prog: dict, ref: dict) -> dict:
     return out
 
 
-def limits(cell: str) -> dict:
-    with open(os.path.join(HERE, "limits", f"{cell}.json")) as f:
+def limits(cell: str, where: str = HERE) -> dict:
+    with open(os.path.join(where, "limits", f"{cell}.json")) as f:
         return json.load(f)["limits"]
 
 

@@ -15,6 +15,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from bench.reference import leaf_norms
 
@@ -25,14 +27,29 @@ def seed_key(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def make_params(model, cfg, seed: int, n: int, dtype=jnp.float32):
-    """Every node's own weights, made on the device in one jitted call."""
-    @jax.jit
+def placement(traffic, chips: int):
+    """Where the program keeps the node axis: over a mesh of the first
+    `chips` devices under the shard_map backend, else on the default
+    device (None)."""
+    if traffic["backend"] != "shard_map":
+        return None
+    from repro.dist.sharding import NODE_AXIS, make_mesh
+
+    mesh = make_mesh((chips,), (NODE_AXIS,), devices=jax.devices()[:chips])
+    return NamedSharding(mesh, P(NODE_AXIS))
+
+
+def make_params(model, cfg, seed: int, n: int, dtype=jnp.float32,
+                sharding=None):
+    """Every node's own weights, made on the device in one jitted call,
+    straight into `sharding` where one is given."""
     def build(key):
         return jax.vmap(lambda k: model.init(k, cfg, dtype))(
             jax.random.split(key, n))
 
-    return build(seed_key(seed))
+    if sharding is None:
+        return jax.jit(build)(seed_key(seed))
+    return jax.jit(build, out_shardings=sharding)(seed_key(seed))
 
 
 def program_world(model, cfg, world):
@@ -51,8 +68,10 @@ def program_world(model, cfg, world):
                  ys=world.ys, x_test=world.x_test, y_test=world.y_test)
 
 
-def build_experiment(model, cfg, traffic, world, params, seed: int):
-    """Experiment(...) with the benchmark's weights in place of its own."""
+def build_experiment(model, cfg, traffic, world, params, seed: int,
+                     sharding=None):
+    """Experiment(...) with the benchmark's weights in place of its own;
+    `sharding` is `placement(...)`'s, whose mesh the program runs on."""
     from repro.comm import CommConfig
     from repro.engine import Experiment, Schedule
 
@@ -65,7 +84,8 @@ def build_experiment(model, cfg, traffic, world, params, seed: int):
         schedule=Schedule(rounds=r, eval_every=r, mode="fused"),
         steps_per_round=meth["local_steps"], batch_size=meth["batch_size"],
         lr=meth["lr"], momentum=meth["momentum"], beta=meth["beta"],
-        s=meth["s"], eval_batch=meth["eval_batch"], seed=seed % (2 ** 31))
+        s=meth["s"], eval_batch=meth["eval_batch"], seed=seed % (2 ** 31),
+        mesh=None if sharding is None else sharding.mesh)
     if (jax.tree.structure(exp.params) != jax.tree.structure(params)
             or any(a.shape != b.shape for a, b in zip(
                 jax.tree.leaves(exp.params), jax.tree.leaves(params)))):

@@ -23,15 +23,42 @@ step's loss is the mean over the first half of its minibatch) or
 Nothing here imports the program.  Memory: the [N, N] @ [N, D] neighbour
 average and per-node evals in blocks of samples, so that the paper's
 50 x 1.2M-parameter CNN fits one chip.
+
+What depends on the model comes from the configuration's module
+(`bench/configs/<config>.py`), each part with a default, the paper's
+classifier:
+
+  loss(params, x, y, cfg)   one node's training loss on one minibatch
+                            (default: the virtual-teacher KL of
+                            `apply`'s logits over `model.num_classes`)
+  score(params, x, y, cfg)  (CE summed, correct summed, labels counted)
+                            over one eval block; labels counted is a
+                            number known from the shapes (default: the
+                            classifier's, one label a sample)
+
+and from the configuration's `reference` block, every key optional:
+
+  eval_block  test samples a node scores at a time (default 1248)
+  node_block  nodes vmapped together in the local step and the eval,
+              one group after another (default: the local step vmaps
+              every node, the eval scores one node at a time)
+
+A node's eval loss is CE over labels counted, its accuracy correct over
+labels counted.  Integer inputs (token ids) and labels keep their type in
+every precision.  With `chips` > 1 the node axis of the parameters, the
+momentum and the data shards lies over the first `chips` devices.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 STRIDE = 7919  # the program's minibatch stride (a prime)
-EVAL_BLOCK = 1248  # test samples per reference eval block
+EVAL_BLOCK = 1248  # test samples per reference eval block, by default
+NODES = "nodes"  # the mesh axis the node axis lies on with chips > 1
 
 
 def _vt_loss(logits, labels, beta, num_classes):
@@ -51,6 +78,32 @@ def _ce_and_correct(logits, labels, num_classes):
     return ce, (jnp.argmax(logits, axis=-1) == labels)
 
 
+def hooks(model):
+    """(loss, score) of a configuration's module, each defaulting to the
+    paper's classifier (see the module docstring)."""
+    def vt_loss(params, x, y, cfg):
+        return _vt_loss(model.apply(params, x, cfg), y,
+                        cfg["method"]["beta"], cfg["model"]["num_classes"])
+
+    def ce_score(params, x, y, cfg):
+        ce, ok = _ce_and_correct(model.apply(params, x, cfg), y,
+                                 cfg["model"]["num_classes"])
+        return (jnp.sum(ce.astype(jnp.float32)),
+                jnp.sum(ok.astype(jnp.float32)), y.size)
+
+    return (getattr(model, "loss", vt_loss),
+            getattr(model, "score", ce_score))
+
+
+def _is_float(a) -> bool:
+    return np.issubdtype(a.dtype, np.floating)
+
+
+def _cast(a, dtype):
+    """`a` on the device, floats in `dtype`; integers keep their type."""
+    return jnp.asarray(a, dtype if _is_float(a) else None)
+
+
 @jax.jit
 def _norms(tree):
     return jax.tree.map(
@@ -68,14 +121,15 @@ def data_arrays(world, dtype):
     """Per-node shards padded to a common length (padding never read)."""
     n = world.num_nodes
     m = max(len(x) for x in world.xs)
-    x_pad = np.zeros((n, m) + world.xs[0].shape[1:], np.float32)
-    y_pad = np.zeros((n, m), np.int32)
+    x0 = world.xs[0]
+    x_pad = np.zeros((n, m) + x0.shape[1:],
+                     np.float32 if _is_float(x0) else x0.dtype)
+    y_pad = np.zeros((n, m) + world.ys[0].shape[1:], np.int32)
     for i, (x, y) in enumerate(zip(world.xs, world.ys)):
         x_pad[i, :len(x)] = x
         y_pad[i, :len(y)] = y
     counts = np.array([len(x) for x in world.xs], np.int32)
-    return (jnp.asarray(x_pad, dtype), jnp.asarray(y_pad),
-            jnp.asarray(counts))
+    return (_cast(x_pad, dtype), jnp.asarray(y_pad), jnp.asarray(counts))
 
 
 def mixing_matrix(world):
@@ -88,6 +142,40 @@ def mixing_matrix(world):
 FAULTS = ("half_batch", "no_exchange")
 
 
+def _in_groups(fn, args, k):
+    """`fn` over the node axis of `args` in groups of `k` nodes, one group
+    after another.  Group g holds nodes g, g + N/k, g + 2N/k, ..., so that
+    with the node axis laid over the chips in equal blocks, and `k` a
+    multiple of their number, every group spans every chip."""
+    n = jax.tree.leaves(args)[0].shape[0]
+    if n % k:
+        raise ValueError(f"node_block {k} does not divide {n} nodes")
+    g = n // k
+    out = jax.lax.map(fn, jax.tree.map(
+        lambda a: jnp.swapaxes(a.reshape((k, g) + a.shape[1:]), 0, 1),
+        args))
+    return jax.tree.map(
+        lambda a: jnp.swapaxes(a, 0, 1).reshape((n,) + a.shape[2:]), out)
+
+
+def _score_all(score, p, x, y, cfg, block):
+    """(CE summed, correct summed, labels counted) of one node over the
+    test set, `block` samples at a time where they tile it."""
+    if x.shape[0] % block:
+        return score(p, x, y, cfg)
+    nb = x.shape[0] // block
+    labels = []
+
+    def one(xy):
+        ce, ok, count = score(p, xy[0], xy[1], cfg)
+        labels.append(count)
+        return ce, ok
+
+    ce, ok = jax.lax.map(one, (x.reshape((nb, block) + x.shape[1:]),
+                               y.reshape((nb, block) + y.shape[1:])))
+    return jnp.sum(ce), jnp.sum(ok), nb * labels[0]
+
+
 def make_reference(model, cfg, rounds, dtype=jnp.float32, fault=None):
     """Returns `call(params, mom, data)`, jitted: R rounds from (params,
     mom) over `data` (from `reference_data`), giving (params, mom, (eval
@@ -95,20 +183,27 @@ def make_reference(model, cfg, rounds, dtype=jnp.float32, fault=None):
     loss, accuracy).  The data are arguments, not constants, so the
     compiled program serves every world of the same shapes."""
     meth = cfg["method"]
-    ncls = cfg["model"]["num_classes"]
+    opts = cfg.get("reference", {})
+    eval_block = opts.get("eval_block", EVAL_BLOCK)
+    node_block = opts.get("node_block")
     bs, steps = meth["batch_size"], meth["local_steps"]
     lr, mu = meth["lr"], meth["momentum"]
-    beta, s = meth["beta"], meth["s"]
+    s = meth["s"]
     highest = dtype == jnp.float32
+    loss, score = hooks(model)
 
     def node_loss(p, x, y):
-        return _vt_loss(model.apply(p, x, cfg), y, beta, ncls)
+        return loss(p, x, y, cfg)
 
     grad_all = jax.vmap(jax.value_and_grad(node_loss))
+    if node_block is not None:
+        vmapped = grad_all
+
+        def grad_all(*a):
+            return _in_groups(lambda g: vmapped(*g), a, node_block)
 
     def call(params, mom, data):
         x_pad, y_pad, counts, mix, x_test, y_test = data
-        used = x_test.shape[0]
 
         def local_step(carry, t):
             params, mom = carry
@@ -117,7 +212,9 @@ def make_reference(model, cfg, rounds, dtype=jnp.float32, fault=None):
             xb = jnp.take_along_axis(
                 x_pad, idx.reshape(idx.shape + (1,) * (x_pad.ndim - 2)),
                 axis=1)
-            yb = jnp.take_along_axis(y_pad, idx, axis=1)
+            yb = jnp.take_along_axis(
+                y_pad, idx.reshape(idx.shape + (1,) * (y_pad.ndim - 2)),
+                axis=1)
             if fault == "half_batch":
                 xb, yb = xb[:, :bs // 2], yb[:, :bs // 2]
             _, g = grad_all(params, xb, yb)
@@ -150,24 +247,15 @@ def make_reference(model, cfg, rounds, dtype=jnp.float32, fault=None):
                 return (params, mom), None
             return (decdiff(params), mom), None
 
-        def evaluate(params):
-            def block(p, xy):
-                ce, ok = _ce_and_correct(model.apply(p, xy[0], cfg), xy[1],
-                                         ncls)
-                return (jnp.sum(ce.astype(jnp.float32)),
-                        jnp.sum(ok.astype(jnp.float32)))
+        def node(p):
+            ce, ok, labels = _score_all(score, p, x_test, y_test, cfg,
+                                        eval_block)
+            return ce / labels, ok / labels
 
-            def node(p):
-                if used % EVAL_BLOCK:
-                    ce, ok = block(p, (x_test, y_test))
-                else:
-                    nb = used // EVAL_BLOCK
-                    ce, ok = jax.lax.map(lambda xy: block(p, xy), (
-                        x_test.reshape((nb, EVAL_BLOCK) + x_test.shape[1:]),
-                        y_test.reshape(nb, EVAL_BLOCK)))
-                    ce, ok = jnp.sum(ce), jnp.sum(ok)
-                return ce / used, ok / used
-            return jax.lax.map(node, params)
+        def evaluate(params):
+            if node_block is None:
+                return jax.lax.map(node, params)
+            return _in_groups(jax.vmap(node), params, node_block)
 
         (params, mom), _ = one_round((params, mom), 0)
         first = evaluate(params)
@@ -184,28 +272,47 @@ def make_reference(model, cfg, rounds, dtype=jnp.float32, fault=None):
     return jax.jit(wrapped)
 
 
-def reference_data(cfg, world, dtype=jnp.float32):
-    """The arrays `make_reference`'s call reads, on the device."""
+def node_sharding(chips: int):
+    """Where the reference lays the node axis: nowhere in particular on
+    one chip, else in equal blocks over the first `chips` devices."""
+    if chips == 1:
+        return None
+    mesh = Mesh(np.array(jax.devices()[:chips]), (NODES,))
+    return NamedSharding(mesh, P(NODES))
+
+
+def reference_data(cfg, world, dtype=jnp.float32, chips=1):
+    """The arrays `make_reference`'s call reads, on the device: the
+    per-node shards on the node axis's chips, the rest on each of them."""
     meth = cfg["method"]
     x_pad, y_pad, counts = data_arrays(world, dtype)
     used = (len(world.x_test) // meth["eval_batch"]) * meth["eval_batch"]
-    return (x_pad, y_pad, counts,
+    data = (x_pad, y_pad, counts,
             jnp.asarray(mixing_matrix(world), dtype),
-            jnp.asarray(world.x_test[:used], dtype),
+            _cast(world.x_test[:used], dtype),
             jnp.asarray(world.y_test[:used].astype(np.int32)))
+    nodes = node_sharding(chips)
+    if nodes is None:
+        return data
+    every = NamedSharding(nodes.mesh, P())
+    return (jax.device_put(data[:3], nodes)
+            + jax.device_put(data[3:], every))
 
 
 def reference_run(model, cfg, world, params0, rounds, calls,
-                  dtype=jnp.float32, fault=None):
+                  dtype=jnp.float32, fault=None, chips=1):
     """Run `calls` calls of R rounds from `params0`; the readings the
     harness compares (see `bench/correct.py`)."""
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}; faults: {FAULTS}")
     call = make_reference(model, cfg, rounds, dtype, fault)
-    data = reference_data(cfg, world, dtype)
+    data = reference_data(cfg, world, dtype, chips)
     params = jax.tree.map(lambda a: jnp.asarray(a, dtype), params0)
-    theta0 = params
     mom = jax.tree.map(jnp.zeros_like, params)
+    nodes = node_sharding(chips)
+    if nodes is not None:
+        params, mom = jax.device_put((params, mom), nodes)
+    theta0 = params
     out = {"loss": [], "acc": []}
     for c in range(calls):
         params, mom, (first, last) = call(params, mom, data)

@@ -5,7 +5,10 @@
 
 A cell is one entry of `workloads` in BENCHMARK.json: a configuration
 (`bench/configs/<config>.json` with `<config>.py` beside it) under a
-traffic mix (`bench/traffic/<traffic>.json`).  Per-layer metrics are
+traffic mix (`bench/traffic/<traffic>.json`).  What depends on the model
+or the data comes from the configuration's module, each part with the
+paper's default: its world (`build_world`, else `bench/world.py`'s), its
+loss and scoring (`bench/reference.py`).  Per-layer metrics are
 readers in `bench/metrics/<metric>.py`, limits of the correctness check
 are in `bench/limits/<cell>.json`; the harness finds each by its name.
 
@@ -52,27 +55,44 @@ def _json(path: str):
         return json.load(f)
 
 
-def cell_spec(workload: str, overrides=None) -> dict:
+def cell_spec(workload: str, overrides=None, where=None) -> dict:
     """Everything BENCHMARK.json and the named files say about a cell.
 
-    `overrides` ({"world": {...}, "traffic": {...}}) shrinks a cell for
-    the tests under bench/tests; benchmark runs never pass it."""
+    `overrides` ({"world": {...}, "traffic": {...}}) shrinks a cell, and
+    `where` names a directory laid out like `bench/` (`cells.json` in
+    BENCHMARK.json's shape, with `configs` and `workloads` only, and
+    `traffic/`, `limits/`) whose cells run under BENCHMARK.json's
+    metrics: both serve the tests under bench/tests; benchmark runs never
+    pass them."""
     bench = _json(os.path.join(ROOT, "BENCHMARK.json"))
-    cells = {w["name"]: w for w in bench["workloads"]}
+    cells_from = bench if where is None else _json(
+        os.path.join(where, "cells.json"))
+    where = where or HERE
+    cells = {w["name"]: w for w in cells_from["workloads"]}
     if workload not in cells:
         raise SystemExit(f"unknown workload {workload!r}; "
                          f"cells: {sorted(cells)}")
     cell = cells[workload]
-    conf = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    conf = {c["name"]: c for c in cells_from["configs"]}[cell["config"]]
     cfg = _json(os.path.join(ROOT, conf["file"]))
-    traffic = _json(os.path.join(HERE, "traffic", f"{cell['traffic']}.json"))
+    traffic = _json(os.path.join(where, "traffic", f"{cell['traffic']}.json"))
     overrides = overrides or {}
     cfg["world"].update(overrides.get("world", {}))
     traffic.update(overrides.get("traffic", {}))
     model = load_module(os.path.splitext(os.path.join(ROOT, conf["file"]))[0]
                         + ".py", f"bench_config_{cell['config']}")
     return {"bench": bench, "cell": cell, "cfg": cfg, "traffic": traffic,
-            "model": model}
+            "model": model, "where": where}
+
+
+def make_world(spec):
+    """The cell's world: the configuration module's `build_world(world
+    block)` where it has one, else the paper's (`bench.world`)."""
+    from bench import world
+
+    model = spec["model"]
+    build = getattr(model, "build_world", world.build_world)
+    return build(spec["cfg"]["world"])
 
 
 def cache_setup(enable_compile_cache):
@@ -118,9 +138,9 @@ def _metrics(names, ctx, units, per_layer):
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
-        require_chip: bool = True, overrides=None) -> dict:
+        require_chip: bool = True, overrides=None, where=None) -> dict:
     """One run of a cell; returns the result line's object."""
-    spec = cell_spec(workload, overrides)
+    spec = cell_spec(workload, overrides, where)
     cell, cfg, traffic, model = (spec["cell"], spec["cfg"], spec["traffic"],
                                  spec["model"])
     import jax
@@ -133,20 +153,21 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     from repro.utils.compile_cache import enable_compile_cache
 
     from bench import correct, drive
-    from bench.reference import reference_run
-    from bench.world import build_world
+    from bench.reference import node_sharding, reference_run
 
     cache_setup(enable_compile_cache)
 
     # ---- set-up: world, weights, the one Experiment, compile, first calls
     t0 = time.perf_counter()
-    world = build_world(cfg["world"])
+    world = make_world(spec)
     n = world.num_nodes
     rounds = traffic["rounds_per_call"]
-    params = drive.make_params(model, cfg, seed, n)
+    place = drive.placement(traffic, cell["chips"])
+    params = drive.make_params(model, cfg, seed, n, sharding=place)
     world_s = time.perf_counter() - t0
     t0 = time.perf_counter()
-    exp = drive.build_experiment(model, cfg, traffic, world, params, seed)
+    exp = drive.build_experiment(model, cfg, traffic, world, params, seed,
+                                 place)
     jax.block_until_ready(exp.params)
     init_s = time.perf_counter() - t0
     t0 = time.perf_counter()
@@ -186,13 +207,18 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
 
     # ---- the reference over the same first calls, then the comparison
     t0 = time.perf_counter()
-    ref = reference_run(model, cfg, world, drive.make_params(
-        model, cfg, seed, n), rounds, traffic["set_up_calls"])
+    chips = cell["chips"]
+    ref = reference_run(
+        model, cfg, world,
+        drive.make_params(model, cfg, seed, n,
+                          sharding=node_sharding(chips)),
+        rounds, traffic["set_up_calls"], chips=chips)
     ref["bytes"] = (traffic["wire_bytes_per_value"] * d_params
                     * world.num_directed_edges * rounds
                     * traffic["set_up_calls"])
     found = correct.gaps(prog, ref)
-    ok, checks = correct.judge(found, correct.limits(workload))
+    ok, checks = correct.judge(found, correct.limits(workload,
+                                                     spec["where"]))
     print(f"bench: set-up {setup_s:.2f} s (world and weights {world_s:.2f} "
           f"s, init {init_s:.2f} s, compile {compile_s:.2f} s), window "
           f"{window_s:.2f} s, {calls} calls, "

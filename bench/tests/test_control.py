@@ -4,18 +4,15 @@ runs at the cells' own sizes; PERF.md gives those readings)."""
 import pytest
 
 from bench import control, correct
-
-SMALL = {"world": {"seed": 3, "nodes": 8, "train_size": 2000,
-                   "test_size": 512, "er_p": 0.5},
-         "traffic": {"rounds_per_call": 4}}
+from bench.tests.test_faults import CELLS
 
 
-@pytest.mark.parametrize("cell", ["mlp.gossip-fp32"])
+@pytest.mark.parametrize("cell", sorted(CELLS))
 def test_bfloat16_control_fails(cell):
+    spec = CELLS[cell]
     lines = control.readings(cell, [5, 6], program=False,
-                             variants=("bf16",), overrides=SMALL,
-                             emit=lambda s: None)
-    lim = correct.limits(cell)
+                             variants=("bf16",), emit=lambda s: None, **spec)
+    lim = correct.limits(cell, spec.get("where", correct.HERE))
     for line in lines:
         ok, checks = correct.judge(line["gaps"], lim)
         assert not ok, checks

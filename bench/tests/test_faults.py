@@ -1,19 +1,24 @@
 """A whole run at a small size on the CPU, sound and with the timed path
 broken underneath: `correct` holds for the sound run and fails for each
-fault the cell can have."""
+fault the cell can have.  Two cells: the paper's MLP cell shrunk, and the
+toy token cell under bench/tests, whose world, loss and scoring come from
+its configuration's hooks."""
+import os
+
 import pytest
 
 from bench import run
 
-CELL = "mlp.gossip-fp32"
+TESTS = os.path.dirname(os.path.abspath(__file__))
 SMALL = {"world": {"seed": 3, "nodes": 8, "train_size": 2000,
                    "test_size": 512, "er_p": 0.5},
          "traffic": {"rounds_per_call": 4}}
+CELLS = {"mlp.gossip-fp32": {"overrides": SMALL},
+         "toy.gossip": {"where": TESTS}}
 
 
-def _run():
-    return run.run(CELL, 5, 0.5, False, require_chip=False,
-                   overrides=SMALL)
+def _run(cell):
+    return run.run(cell, 5, 0.5, False, require_chip=False, **CELLS[cell])
 
 
 def _frozen_state(monkeypatch):
@@ -71,15 +76,17 @@ FAULTS = {"frozen_state": _frozen_state, "half_batch": _half_batch,
           "no_exchange": _no_exchange, "altered_answer": _altered_answer}
 
 
-def test_sound_run_is_correct():
-    res = _run()
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_sound_run_is_correct(cell):
+    res = _run(cell)
     assert res["correct"], res["checks"]
     assert res["attempted"] >= 1
     assert list(res)[-1] == "checks"
 
 
+@pytest.mark.parametrize("cell", sorted(CELLS))
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_fault_is_not_correct(fault, monkeypatch):
+def test_fault_is_not_correct(fault, cell, monkeypatch):
     FAULTS[fault](monkeypatch)
-    res = _run()
+    res = _run(cell)
     assert res["correct"] is False, res["checks"]
