@@ -18,7 +18,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from bench.reference import leaf_norms
+from bench.reference import change_norms, leaf_norms
 
 
 def seed_key(seed: int):
@@ -118,8 +118,10 @@ def call(exp, rounds):
 
 def set_up_calls(exp, rounds: int, calls: int):
     """The first `calls` calls, through the window's own call, with the
-    readings that the reference follows."""
-    theta0 = _copy(exp.params)
+    readings that the reference follows.  Where the weights lie over
+    several chips, their starting point waits on the host."""
+    spread = len(jax.tree.leaves(exp.params)[0].sharding.device_set) > 1
+    theta0 = jax.device_get(exp.params) if spread else _copy(exp.params)
     out = {"loss": [], "acc": []}
     for c in range(calls):
         hist = call(exp, rounds)
@@ -129,7 +131,8 @@ def set_up_calls(exp, rounds: int, calls: int):
         out["loss"].append(float(np.mean(hist[-1].loss_per_node)))
         out["acc"].append(float(np.mean(hist[-1].acc_per_node)))
         out["bytes"] = float(hist[-1].bytes_on_wire)
-    out["dparam"] = leaf_norms(_diff(exp.params, theta0))
+    out["dparam"] = (change_norms(exp.params, theta0) if spread
+                     else leaf_norms(_diff(exp.params, theta0)))
     return out
 
 

@@ -22,7 +22,10 @@ step's loss is the mean over the first half of its minibatch) or
 
 Nothing here imports the program.  Memory: the [N, N] @ [N, D] neighbour
 average and per-node evals in blocks of samples, so that the paper's
-50 x 1.2M-parameter CNN fits one chip.
+50 x 1.2M-parameter CNN fits one chip.  With the node axis over several
+chips the average is taken one leaf at a time instead, since the [N, D]
+concatenation would be gathered whole onto every chip.  The call donates
+its carry, and the starting point waits on the host.
 
 What depends on the model comes from the configuration's module
 (`bench/configs/<config>.py`), each part with a default, the paper's
@@ -117,6 +120,14 @@ def leaf_norms(tree):
     return {jax.tree_util.keystr(k): float(v) for k, v in flat}
 
 
+def change_norms(params, theta0):
+    """`leaf_norms` of `params` less `theta0`, host arrays that are put
+    one leaf at a time where that leaf of `params` lies."""
+    return leaf_norms(jax.tree.map(
+        lambda a, b: a.astype(jnp.float32)
+        - jax.device_put(b, a.sharding).astype(jnp.float32), params, theta0))
+
+
 def data_arrays(world, dtype):
     """Per-node shards padded to a common length (padding never read)."""
     n = world.num_nodes
@@ -176,12 +187,41 @@ def _score_all(score, p, x, y, cfg, block):
     return jnp.sum(ce), jnp.sum(ok), nb * labels[0]
 
 
-def make_reference(model, cfg, rounds, dtype=jnp.float32, fault=None):
+def _decdiff_by_leaf(params, mix, s, dtype):
+    """The DecDiff step (Eq. 5-6) one leaf at a time, with no [N, D]
+    concatenation of the model: each node's distance to its neighbours'
+    average, summed over the leaves, then each leaf's move, its average
+    taken again.  The barriers make each leaf wait for the one before it,
+    so that one leaf's copy gathered over the node axis is alive at a
+    time."""
+    leaves, tree = jax.tree.flatten(params)
+
+    def diff(l):
+        return jnp.einsum("ij,j...->i...", mix, l) - l
+
+    sq = jnp.zeros(leaves[0].shape[:1], leaves[0].dtype)
+    for l in leaves:
+        l, sq = jax.lax.optimization_barrier((l, sq))
+        sq = sq + jnp.sum(jnp.square(diff(l)), axis=tuple(range(1, l.ndim)))
+    d = jnp.sqrt(sq)
+    out, last = [], d
+    for l in leaves:
+        l, last = jax.lax.optimization_barrier((l, last))
+        last = (l + diff(l) / (d.reshape((-1,) + (1,) * (l.ndim - 1)) + s)
+                ).astype(dtype)
+        out.append(last)
+    return jax.tree.unflatten(tree, out)
+
+
+def make_reference(model, cfg, rounds, dtype=jnp.float32, fault=None,
+                   chips=1):
     """Returns `call(params, mom, data)`, jitted: R rounds from (params,
     mom) over `data` (from `reference_data`), giving (params, mom, (eval
     at round 0, eval at round R-1)); an eval is per-node (mean test CE
     loss, accuracy).  The data are arguments, not constants, so the
-    compiled program serves every world of the same shapes."""
+    compiled program serves every world of the same shapes; params and
+    mom are donated.  With the node axis over `chips` > 1 chips the
+    DecDiff step goes leaf by leaf (`_decdiff_by_leaf`)."""
     meth = cfg["method"]
     opts = cfg.get("reference", {})
     eval_block = opts.get("eval_block", EVAL_BLOCK)
@@ -225,6 +265,8 @@ def make_reference(model, cfg, rounds, dtype=jnp.float32, fault=None):
             return (params, mom), None
 
         def decdiff(params):
+            if chips > 1:
+                return _decdiff_by_leaf(params, mix, s, dtype)
             leaves, tree = jax.tree.flatten(params)
             n = leaves[0].shape[0]
             flat = jnp.concatenate([l.reshape(n, -1) for l in leaves],
@@ -269,7 +311,7 @@ def make_reference(model, cfg, rounds, dtype=jnp.float32, fault=None):
                 return call(*a)
         return call(*a)
 
-    return jax.jit(wrapped)
+    return jax.jit(wrapped, donate_argnums=(0, 1))
 
 
 def node_sharding(chips: int):
@@ -302,17 +344,19 @@ def reference_data(cfg, world, dtype=jnp.float32, chips=1):
 def reference_run(model, cfg, world, params0, rounds, calls,
                   dtype=jnp.float32, fault=None, chips=1):
     """Run `calls` calls of R rounds from `params0`; the readings the
-    harness compares (see `bench/correct.py`)."""
+    harness compares (see `bench/correct.py`).  The calls update their
+    carry in place (`params0` too, where it is already in `dtype` and
+    placed), and the starting point waits on the host for `dparam`."""
     if fault is not None and fault not in FAULTS:
         raise ValueError(f"unknown fault {fault!r}; faults: {FAULTS}")
-    call = make_reference(model, cfg, rounds, dtype, fault)
+    call = make_reference(model, cfg, rounds, dtype, fault, chips)
     data = reference_data(cfg, world, dtype, chips)
     params = jax.tree.map(lambda a: jnp.asarray(a, dtype), params0)
     mom = jax.tree.map(jnp.zeros_like, params)
     nodes = node_sharding(chips)
     if nodes is not None:
         params, mom = jax.device_put((params, mom), nodes)
-    theta0 = params
+    theta0 = jax.device_get(params)
     out = {"loss": [], "acc": []}
     for c in range(calls):
         params, mom, (first, last) = call(params, mom, data)
@@ -321,7 +365,5 @@ def reference_run(model, cfg, world, params0, rounds, calls,
             out["mom1"] = leaf_norms(mom)
         out["loss"].append(float(jnp.mean(last[0].astype(jnp.float32))))
         out["acc"].append(float(jnp.mean(last[1].astype(jnp.float32))))
-    out["dparam"] = leaf_norms(jax.tree.map(
-        lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-        params, theta0))
+    out["dparam"] = change_norms(params, theta0)
     return out

@@ -1,7 +1,9 @@
 """A cell on several chips, on 8 virtual CPU devices in a child process:
 the reference with the node axis over 4 of them reads what it reads on
-one, the weights land in the program's mesh placement, and the program
-under the shard_map backend is `correct` against that reference."""
+one, `dparam` reads the same with the starting point on the host as on
+the device, the weights land in the program's mesh placement, and the
+program under the shard_map backend is `correct` against that
+reference."""
 import json
 import os
 import subprocess
@@ -14,10 +16,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 TESTS = os.path.join(ROOT, "bench", "tests")
 CELL = "toy.pods4"
 SEED = 2 ** 33 + 11
-# With the node axis over 4 devices the partitioned sums (`mix @ flat`
-# over the node axis, the vmapped groups' reductions) run in another order
-# than on one device, so float32 readings differ in their last bits: up to
-# 3.7e-7 relative (the first call's momentum of the embedding) on the CPU.
+# With the node axis over 4 devices the reference takes the neighbour
+# average leaf by leaf and the partitioned sums (the average, the vmapped
+# groups' reductions) run in another order than on one device, so float32
+# readings differ in their last bits: up to 3.7e-7 relative (the first
+# call's momentum of the embedding) on the CPU.
 # 1e-6 is half of the toy's tightest limit (2e-6, bench/tests/limits), so
 # laying the reference over the chips moves no verdict by more than that.
 REL = 1e-6
@@ -28,7 +31,8 @@ def child():
     import jax
 
     from bench import drive
-    from bench.reference import node_sharding, reference_data, reference_run
+    from bench.reference import (leaf_norms, make_reference, node_sharding,
+                                 reference_data, reference_run)
     from bench.run import cell_spec, make_world, run
 
     spec = cell_spec(CELL, where=TESTS)
@@ -40,13 +44,32 @@ def child():
         model, cfg, world,
         drive.make_params(model, cfg, SEED, n, sharding=node_sharding(c)),
         rounds, calls, chips=c) for c in (1, chips)}
+    # dparam with the starting point kept on the device, beside the host
+    # copy that reference_run and set_up_calls keep on several chips
+    params = drive.make_params(model, cfg, SEED, n,
+                               sharding=node_sharding(chips))
+    theta0 = drive._copy(params)
+    mom = jax.tree.map(jax.numpy.zeros_like, params)
+    call = make_reference(model, cfg, rounds, chips=chips)
+    data = reference_data(cfg, world, chips=chips)
+    for _ in range(calls):
+        params, mom, _ = call(params, mom, data)
+    on_device = {"reference": leaf_norms(drive._diff(params, theta0))}
     place = drive.placement(traffic, chips)
+    exp = drive.build_experiment(
+        model, cfg, traffic, world,
+        drive.make_params(model, cfg, SEED, n, sharding=place), SEED, place)
+    theta0 = drive._copy(exp.params)
+    on_host = {"reference": readings[chips]["dparam"],
+               "program": drive.set_up_calls(exp, rounds, calls)["dparam"]}
+    on_device["program"] = leaf_norms(drive._diff(exp.params, theta0))
+    del exp
     params = drive.make_params(model, cfg, SEED, n, sharding=place)
     plain = drive.make_params(model, cfg, SEED, n)
-    data = reference_data(cfg, world, chips=chips)
     leaves = jax.tree.leaves(params)
     out = {
         "readings": readings,
+        "dparam": {"on_host": on_host, "on_device": on_device},
         "weights_spec": [str(a.sharding.spec) for a in leaves],
         "weights_devices": sorted({d.id for a in leaves
                                    for d in a.sharding.device_set}),
@@ -83,6 +106,12 @@ def test_four_chips_read_as_one(pods):
             a, b = [a], [b]
         for x, y in zip(a, b):
             assert abs(x - y) <= REL * abs(x), (key, one, four)
+
+
+@pytest.mark.parametrize("side", ["reference", "program"])
+def test_theta0_on_the_host_reads_as_on_the_device(pods, side):
+    got = pods["dparam"]
+    assert got["on_host"][side] == got["on_device"][side]
 
 
 def test_weights_land_in_the_program_mesh(pods):
