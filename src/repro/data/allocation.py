@@ -125,16 +125,18 @@ def allocation_gini(allocation: List[np.ndarray], labels: np.ndarray = None) -> 
 
 def pad_node_datasets(xs: List[np.ndarray], ys: List[np.ndarray]
                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Pad per-node datasets to a common length for vmapped training.
+    """Pad per-node datasets to a common length, in their own order.
 
-    Returns (x_pad [N, M, ...], y_pad [N, M], counts [N]).  Padding samples
-    repeat real ones (so they're harmless) but training draws minibatches
-    only from the first `counts[i]` entries via modular indexing.
+    Returns (x_pad [N, M, ...], y_pad [N, M, ...], counts [N]).  Padding
+    samples repeat real ones, so row k of node i is sample
+    `k mod counts[i]`.  The engine trains from
+    :func:`stride_ordered_shards` instead, whose rows are in minibatch
+    order.
     """
     n = len(xs)
     m = max(len(x) for x in xs)
     x_pad = np.zeros((n, m) + xs[0].shape[1:], xs[0].dtype)
-    y_pad = np.zeros((n, m), ys[0].dtype)
+    y_pad = np.zeros((n, m) + ys[0].shape[1:], ys[0].dtype)
     counts = np.zeros(n, np.int64)
     for i, (x, y) in enumerate(zip(xs, ys)):
         k = len(x)
@@ -143,3 +145,33 @@ def pad_node_datasets(xs: List[np.ndarray], ys: List[np.ndarray]
         x_pad[i] = np.concatenate([x] * reps)[:m]
         y_pad[i] = np.concatenate([y] * reps)[:m]
     return x_pad, y_pad, counts
+
+
+def stride_ordered_shards(xs: List[np.ndarray], ys: List[np.ndarray],
+                          batch_size: int, stride: int
+                          ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Per-node datasets laid out in minibatch order, for vmapped training.
+
+    Returns (x [N, M + B - 1, F], y [N, M + B - 1, ...], counts [N]) with
+    M = max counts, B = `batch_size` and F the number of values in one x
+    sample: x rows hold each sample flattened (the TPU tiles an array's
+    last two axes, so a 28x28 sample kept as two axes would pad to 32x128),
+    y rows keep their shape (a label or a row of tokens).  Row k of node i
+    holds sample `((k mod c_i) * stride) mod c_i` of that node
+    (c_i = counts[i]).  Since ((a mod c) * s) mod c equals (a * s) mod c,
+    the B consecutive rows from `(t * B) mod c_i` are the samples
+    `((t * B + j) * stride) mod c_i`, j < B: the stride schedule of
+    :class:`repro.data.pipeline.Batcher`, read as one contiguous window.
+    The B - 1 rows past M let a window that starts at any row below c_i
+    run on without wrapping.
+    """
+    counts = np.array([len(x) for x in xs], np.int64)
+    k = np.arange(int(counts.max()) + batch_size - 1)
+    x_out = np.empty((len(xs), len(k), int(np.prod(xs[0].shape[1:]))),
+                     xs[0].dtype)
+    y_out = np.empty((len(ys), len(k)) + ys[0].shape[1:], ys[0].dtype)
+    for i, (x, y, c) in enumerate(zip(xs, ys, counts)):
+        order = ((k % c) * stride) % c
+        x_out[i] = x.reshape(c, -1)[order]
+        y_out[i] = y[order]
+    return x_out, y_out, counts

@@ -255,19 +255,19 @@ def _make_gradient_exchange(exp):
     their data; we descend along the p_ij-weighted mean of their gradients.
     Runs per block row: `rows` slices the neighbour table and the
     replicated minibatch keys; the neighbour DATA is read out of the full
-    (replicated) padded arrays, which is what lets the walk cross pods
-    without a collective."""
+    (replicated) stride-ordered shards, which is what lets the walk cross
+    pods without a collective.  Slot d of round r reads its senders'
+    minibatch of step `r*max_deg + d` (`Batcher.take_nodes`)."""
     cfg = exp.train
     batcher = exp.batcher
     counts = exp.counts
     nbr_idx, nbr_weight = exp.nbr_idx, exp.nbr_weight
-    x_pad, y_pad = exp.x_pad, exp.y_pad
+    x_shards, y_shards = exp.x_shards, exp.y_shards
     n = exp.n
     max_deg = int(nbr_idx.shape[1])
     v_grad = jax.vmap(exp._grad_fn, in_axes=(0, 0, 0, 0))
 
     def gradient_exchange(rows, params, mask, round_idx, rng):
-        bs = cfg.batch_size
         nbr_idx_r = rows(nbr_idx)
         nbr_w_r = rows(nbr_weight)
         r = int(nbr_idx_r.shape[0])
@@ -275,12 +275,8 @@ def _make_gradient_exchange(exp):
         def body(carry, d):
             acc, tot = carry
             j = nbr_idx_r[:, d]  # [r] neighbour ids in slot d
-            cj = counts[j]
-            base = (round_idx * max_deg + d) * bs
-            bidx = (base + jnp.arange(bs, dtype=jnp.int32)[None, :]) * batcher.stride
-            bidx = bidx % jnp.maximum(cj[:, None], 1)
-            xj = x_pad[j[:, None], bidx]  # [r, bs, ...]
-            yj = y_pad[j[:, None], bidx]
+            xj, yj = batcher.take_nodes(x_shards, y_shards, counts, j,
+                                        round_idx * max_deg + d)
             keys = rows(jax.random.split(jax.random.fold_in(rng, d), n))
             g = v_grad(params, xj, yj, keys)  # grad of F_j at w_i
             w_d = nbr_w_r[:, d] * mask[:, d]
@@ -332,7 +328,7 @@ def _make_sparse_gradient_exchange(exp):
     cfg = exp.train
     batcher = exp.batcher
     counts = exp.counts
-    x_pad, y_pad = exp.x_pad, exp.y_pad
+    x_shards, y_shards = exp.x_shards, exp.y_shards
     n = exp.n
     plan = exp.sparse_plan
     max_deg = int(exp.topo.max_degree)
@@ -346,7 +342,6 @@ def _make_sparse_gradient_exchange(exp):
         return jnp.concatenate([p, jnp.zeros((1,) + p.shape[1:], p.dtype)])
 
     def gradient_exchange(ctx, params, link_u, live_e, round_idx, rng):
-        bs = cfg.batch_size
         pod = ctx.pod if ctx.pod is not None else jnp.int32(0)
         lr_ge = cfg.ge_lr if cfg.ge_lr is not None else cfg.lr
         out = params
@@ -369,13 +364,8 @@ def _make_sparse_gradient_exchange(exp):
             def body(carry, k):
                 acc, tot = carry
                 j = src[:, k]  # [b] sender ids in slot k
-                cj = counts[j]
-                base = (round_idx * max_deg + k) * bs
-                bidx = (base + jnp.arange(bs, dtype=jnp.int32)[None, :]) \
-                    * batcher.stride
-                bidx = bidx % jnp.maximum(cj[:, None], 1)
-                xj = x_pad[j[:, None], bidx]  # [b, bs, ...]
-                yj = y_pad[j[:, None], bidx]
+                xj, yj = batcher.take_nodes(x_shards, y_shards, counts, j,
+                                            round_idx * max_deg + k)
                 keys = jax.random.split(jax.random.fold_in(rng, k), n)[gid]
                 g = v_grad(p_b, xj, yj, keys)  # grad of F_j at w_i
                 w_k = w_slot[:, k]
@@ -912,7 +902,7 @@ def _unpack_states(exp, rest):
 def _build_vmap_round(exp):
     """The dense lowering: the round body under the identity context."""
     body = _make_round_body(exp, loss_reduce=_identity_rows)
-    x, y = exp.x_pad, exp.y_pad
+    x, y = exp.x_shards, exp.y_shards
 
     def round_fn(params, opt, *rest):
         comm_state, dyn_state, time_state, obs_state, round_idx, rng = \
@@ -1011,6 +1001,6 @@ def _build_shardmap_round(exp):
         check_vma=False)
 
     def round_fn(params, opt, *rest):
-        return sharded(params, opt, *rest, exp.x_pad, exp.y_pad)
+        return sharded(params, opt, *rest, exp.x_shards, exp.y_shards)
 
     return round_fn

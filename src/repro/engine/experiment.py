@@ -50,7 +50,7 @@ import numpy as np
 from repro.comm import (WIRES, CommConfig, EdgeGossipTransport,
                         GossipTransport, SparseEdgeGossipTransport)
 from repro.core.virtual_teacher import make_loss_fn
-from repro.data.allocation import pad_node_datasets
+from repro.data.allocation import stride_ordered_shards
 from repro.data.pipeline import Batcher
 from repro.dist.sharding import NODE_AXIS, auto_mesh, make_mesh
 from repro.dynamics import GraphProcess
@@ -294,9 +294,14 @@ class Experiment:
         self.mesh = (auto_mesh(mesh) if mesh is not None else
                      _default_mesh(self.n) if backend == "shard_map" else None)
 
-        x_pad, y_pad, counts = pad_node_datasets(world.xs, world.ys)
-        self.x_pad = jnp.asarray(x_pad)
-        self.y_pad = jnp.asarray(y_pad.astype(np.int32))
+        # One copy of the training shards on the device, rows in minibatch
+        # order: each local step reads one contiguous window a node.
+        self.batcher = Batcher(batch_size=train.batch_size,
+                               sample_shape=world.xs[0].shape[1:])
+        x_shards, y_shards, counts = stride_ordered_shards(
+            world.xs, world.ys, self.batcher.batch_size, self.batcher.stride)
+        self.x_shards = jnp.asarray(x_shards)
+        self.y_shards = jnp.asarray(y_shards.astype(np.int32))
         self.counts = jnp.asarray(counts.astype(np.int32))
         self.x_test = jnp.asarray(world.x_test)
         self.y_test = jnp.asarray(world.y_test.astype(np.int32))
@@ -325,7 +330,6 @@ class Experiment:
 
         self.optimizer = sgd_momentum(lr=train.lr, momentum=train.momentum)
         self.loss_fn = make_loss_fn(self.method.loss, beta=train.beta)
-        self.batcher = Batcher(batch_size=train.batch_size)
         self._train_step = make_train_step(self.model, self.optimizer,
                                            self.loss_fn)
         self._grad_fn = make_grad_fn(self.model, self.loss_fn)
@@ -735,6 +739,11 @@ class Experiment:
         after the initial local training, matching the paper's Fig. 1
         x-axis).  Repeated calls continue from the current state (round
         indices restart, so the deterministic batch schedule repeats).
+        Step t of a call reads each node's minibatch as one window of its
+        stride-ordered shard (`data.pipeline.Batcher`), whose int32 start
+        wraps only past 2**31 / batch_size steps; the earlier per-row
+        gather's int32 index product wrapped after 2,118 rounds a call at
+        4 steps of batch 32, so calls that long read other samples then.
 
         Verbose round lines go through the ``repro.obs.round`` logging
         stream
