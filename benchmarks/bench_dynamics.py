@@ -61,7 +61,7 @@ WORLDS = [("ba", dict(topology="barabasi_albert", m=2)),
 
 
 def make_world(graph_kwargs, nodes=16, seed=0, dynamics=None):
-    """The 16-node smoke worlds (bench_engine's scaled comm smoke config)."""
+    """The 16-node smoke worlds: synth-mnist at scale 0.03, a 64-32 MLP."""
     from repro.models.mlp_cnn import make_mlp
 
     return World.synthetic(dataset="synth-mnist", nodes=nodes, seed=seed,

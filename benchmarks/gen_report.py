@@ -1,4 +1,4 @@
-"""Regenerate EXPERIMENTS.md from artifacts (dry-run, roofline, paper suite).
+"""Regenerate EXPERIMENTS.md from the paper suite's artifacts.
 
     PYTHONPATH=src python -m benchmarks.gen_report
 """
@@ -8,7 +8,6 @@ import json
 import os
 from typing import Optional
 
-from benchmarks import roofline as rl
 from benchmarks.common import load_results
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
@@ -189,55 +188,6 @@ def write_bench_comm() -> str:
     return path
 
 
-def write_bench_engine() -> str:
-    """Fold the engine-runner sweep into BENCH_engine.json: rounds/sec per
-    (backend, schedule mode) on the 16-node BA smoke world, plus the
-    acceptance verdict — the scan-fused schedule must reach >= 2x the
-    per-round Python loop's rounds/sec on the vmap backend (the repo's
-    first runner-layer perf gate; see benchmarks/bench_engine.py)."""
-    res = load_results("engine_runner") or {}
-    if not res:
-        print("engine_runner artifact missing; BENCH_engine.json not "
-              "rewritten (run python -m benchmarks.bench_engine)")
-        return None
-    speedup = res.get("fused_speedup_vmap", 0.0)
-    wire_ratio = res.get("encoded_over_decoded_shardmap")
-    payload = {
-        "world": res.get("world", {}),
-        "rows": res.get("rows", []),
-        "wire_rows": res.get("wire_rows", []),
-        "acceptance": {
-            "criterion": "scan-fused schedule >= 2x rounds/sec vs the "
-                         "per-round Python loop (vmap backend, 16-node BA "
-                         "smoke world)",
-            "fused_speedup_vmap": speedup,
-            "passed": bool(speedup >= 2.0),
-            "note": "modes are bit-identical in math (pinned by "
-                    "tests/test_engine.py); this measures pure execution "
-                    "strategy: one lax.scan program dispatched once vs one "
-                    "XLA dispatch per round plus jitted eval calls.",
-        },
-        "wire_acceptance": {
-            "criterion": "shard_map encoded-payload exchange (the default "
-                         "wire) >= 0.9x the decoded-rows oracle's "
-                         "rounds/sec (int8 event-triggered transport; 0.9 "
-                         "absorbs shared-CPU timing noise — the encoded "
-                         "wire also ships ~4x fewer bytes across the pod "
-                         "axis)",
-            "encoded_over_decoded_shardmap": wire_ratio,
-            "passed": None if wire_ratio is None else bool(wire_ratio >= 0.9),
-            "note": "wires are informationally identical (one exchange "
-                    "step is bitwise equal across wires; pinned by "
-                    "tests/test_engine.py); null when the bench host had "
-                    "no pod axis.",
-        },
-    }
-    path = os.path.join(ROOT, "BENCH_engine.json")
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=2)
-    return path
-
-
 def write_bench_dynamics() -> Optional[str]:
     """Fold the dynamics suite into BENCH_dynamics.json: accuracy/bytes for
     dense fp32 and int8+adaptive DecDiff+VT under every catalog
@@ -359,185 +309,6 @@ def write_bench_time() -> Optional[str]:
     return path
 
 
-def write_bench_scale() -> Optional[str]:
-    """Fold the node-axis scaling sweep into BENCH_scale.json: rounds/sec
-    per (N, layout) on the tiny-MLP BA gossip world, the 10^5-receiver
-    kernel tier, the 10^6-node builder tier, the dynamics tier
-    (int8+adaptive per-edge transport under 20% dropout on the sparse
-    engine), and the acceptance verdicts — the sparse layout must complete
-    an engine round at >= 10^4 nodes at a node count where the dense layout
-    is skipped (projected memory wall) or >= 5x slower, and the dynamics
-    tier must run there with the realized live fraction at its 1 - p
-    stationary value (see benchmarks/bench_scale.py)."""
-    res = load_results("scale_sweep") or {}
-    if not res:
-        # never clobber a committed BENCH_scale.json just because
-        # artifacts/ was cleaned; the full (non --smoke) sweep refreshes it.
-        print("scale_sweep artifact missing; BENCH_scale.json not "
-              "rewritten (run python -m benchmarks.bench_scale)")
-        return None
-    rows = res.get("rows", [])
-    by_n = {}
-    for r in rows:
-        by_n.setdefault(r["nodes"], {})[r["layout"]] = r
-    passing = []
-    for n, pair in sorted(by_n.items()):
-        dn, sp = pair.get("dense"), pair.get("sparse")
-        if n < 10_000 or sp is None or "rounds_per_sec" not in sp:
-            continue
-        dense_walled = (dn is None or dn.get("skipped") is not None
-                        or (dn.get("rounds_per_sec", 0.0)
-                            <= sp["rounds_per_sec"] / 5.0))
-        if dense_walled:
-            passing.append({"nodes": n,
-                            "sparse_rounds_per_sec": sp["rounds_per_sec"],
-                            "dense": (dn or {}).get("skipped",
-                                                    "not swept")
-                            if dn is None or "rounds_per_sec" not in dn
-                            else f"{dn['rounds_per_sec']:.3f} rounds/s"})
-    dyn = res.get("dynamics")
-    dyn_passed = bool(
-        dyn and dyn.get("nodes", 0) >= 10_000
-        and dyn.get("rounds_per_sec", 0.0) > 0.0
-        and abs(dyn.get("live_frac_mean", 0.0)
-                - (1.0 - dyn.get("dropout_p", 0.2))) < 0.02
-        and 0.0 < dyn.get("trig_frac_mean", 0.0) <= 1.0)
-    payload = {
-        "world": res.get("world", {}),
-        "dense_bytes_budget": res.get("dense_bytes_budget"),
-        "rows": rows,
-        "kernel": res.get("kernel"),
-        "builder": res.get("builder"),
-        "dynamics": dyn,
-        "acceptance": {
-            "criterion": "sparse layout completes engine rounds at >= 10^4 "
-                         "nodes where dense is memory-walled (projected "
-                         "block over budget) or >= 5x slower",
-            "passed": bool(passing),
-            "passing_points": passing,
-            "dynamics": {
-                "criterion": "int8+adaptive per-edge transport under 20% "
-                             "i.i.d. edge dropout completes at >= 10^4 "
-                             "nodes on the sparse engine, with the "
-                             "realized live fraction within 0.02 of the "
-                             "1 - p stationary value and a sane triggered "
-                             "fraction",
-                "passed": dyn_passed,
-            },
-            "note": "dense and sparse are bit-identical where both run — "
-                    "methods x transports x dynamics x backends, pinned in "
-                    "tests/test_sparse_parity.py; this artifact records "
-                    "what the sparse layout buys past the dense wall.",
-        },
-    }
-    path = os.path.join(ROOT, "BENCH_scale.json")
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=2)
-    return path
-
-
-def write_bench_obs() -> Optional[str]:
-    """Fold the telemetry bench into BENCH_obs.json: the all-channels
-    overhead pair on the 16-node fused schedule, the ledger/trace
-    validation results, and the acceptance verdicts — all-channels
-    rounds/sec within 5% of telemetry-off, and the exported Chrome
-    trace's per-edge transfer-span bytes summing EXACTLY to the run's
-    bytes_on_wire (see benchmarks/bench_obs.py)."""
-    res = load_results("obs_suite") or {}
-    if not res:
-        # never clobber a committed BENCH_obs.json just because
-        # artifacts/ was cleaned; the full (non --smoke) run refreshes it.
-        print("obs_suite artifact missing; BENCH_obs.json not "
-              "rewritten (run python -m benchmarks.bench_obs)")
-        return None
-    payload = {
-        "world": res.get("world"),
-        "rows": res.get("rows", []),
-        "ledger": res.get("ledger"),
-        "trace": res.get("trace"),
-        "dispersion": res.get("dispersion"),
-        "acceptance": {
-            "criterion": "with EVERY telemetry channel accumulating in "
-                         "the scan carry (steps, compute seconds, "
-                         "accuracy, trigger counts, exact bytes, "
-                         "staleness, landing latency, consensus, drift), "
-                         "the fused schedule's rounds/sec stays within "
-                         "5% of telemetry=None on the 16-node BA world",
-            "overhead_frac": res.get("overhead_frac"),
-            "passed": bool(res.get("overhead_passed")),
-            "trace": {
-                "criterion": "the Chrome-trace export's per-edge "
-                             "transfer spans carry exact payload bytes "
-                             "that sum to RoundMetrics.bytes_on_wire",
-                "passed": bool(res.get("trace", {}).get("bytes_exact")),
-            },
-        },
-    }
-    path = os.path.join(ROOT, "BENCH_obs.json")
-    with open(path, "w") as f:
-        json.dump(payload, f, indent=2)
-    return path
-
-
-def obs_section() -> str:
-    """The observability tentpole's report section, built from the RUN
-    LEDGER the bench emitted (not from in-memory results): per-node
-    accuracy dispersion and the per-edge byte distribution at the final
-    eval round — the distributional surface the node-mean tables hide."""
-    res = load_results("obs_suite") or {}
-    if not res:
-        return ""
-    from benchmarks.common import ART_DIR
-    from repro.obs import read_ledger
-
-    ledger_path = os.path.join(ART_DIR, res["ledger"]["path"])
-    if not os.path.exists(ledger_path):
-        return ""
-    manifest, rounds, summaries = read_ledger(ledger_path)
-    last = rounds[-1]
-    detail = {k: [float(x) for x in v]
-              for k, v in last.get("detail", {}).items()}
-    out = ["### Observability tentpole — telemetry channels "
-           f"(16-node BA, {manifest['method']}, all channels)\n",
-           "Read back from the schema-validated run ledger "
-           f"(`{res['ledger']['path']}`: {res['ledger']['counts']}); "
-           "per-edge channels are in the canonical (dst, src) directed-"
-           "edge order.  BENCH_obs.json carries the ≤5% overhead and "
-           "exact-trace-bytes acceptance gates "
-           f"(overhead {res['overhead_frac'] * 100:+.1f}%).\n"]
-
-    def pct(vals, q):
-        v = sorted(vals)
-        return v[min(len(v) - 1, int(q / 100 * len(v)))]
-
-    acc = last["acc_per_node"]
-    out.append("| channel | min | p50 | p95 | max |")
-    out.append("|---|---|---|---|---|")
-    out.append(f"| node accuracy | {min(acc):.4f} | {pct(acc, 50):.4f} | "
-               f"{pct(acc, 95):.4f} | {max(acc):.4f} |")
-    for name, scale, fmt in (("node_steps", 1, ".0f"),
-                             ("node_compute", 1, ".1f"),
-                             ("edge_bytes", 1e6, ".2f"),
-                             ("edge_trigger", 1, ".0f"),
-                             ("edge_staleness", 1, ".0f"),
-                             ("drift", 1, ".3f")):
-        if name not in detail:
-            continue
-        v = [x / scale for x in detail[name]]
-        label = name + (" (MB)" if scale == 1e6 else "")
-        out.append(f"| {label} | {min(v):{fmt}} | {pct(v, 50):{fmt}} | "
-                   f"{pct(v, 95):{fmt}} | {max(v):{fmt}} |")
-    if summaries:
-        s = summaries[-1]
-        out.append("")
-        out.append(f"Ledger summary: {s['rounds_per_sec']:.2f} rounds/s "
-                   f"wall ({s['wall_s']:.1f}s"
-                   + (f", cold compile {s['compile_s']:.1f}s"
-                      if s.get("cold_compile") else "") + ").")
-    out.append("")
-    return "\n".join(out)
-
-
 def time_section() -> str:
     rows = load_results("time_suite") or []
     if not rows:
@@ -590,114 +361,6 @@ def dynamics_section() -> str:
     return "\n".join(out)
 
 
-def engine_section() -> str:
-    res = load_results("engine_runner") or {}
-    if not res:
-        return ""
-    out = ["### Engine runner — scan-fused schedule vs per-round loop "
-           "(16-node BA smoke, DecDiff+VT)\n",
-           "Same math bit-for-bit (tests/test_engine.py); only the "
-           "execution strategy differs.  BENCH_engine.json carries the "
-           ">= 2x acceptance gate.\n",
-           "| backend | schedule | rounds/s | timed wall s | compile+first s |",
-           "|---|---|---|---|---|"]
-    for r in res.get("rows", []):
-        out.append(f"| {r['backend']} | {r['mode']} | "
-                   f"{r['rounds_per_sec']:.1f} | {r['wall_s']:.2f} | "
-                   f"{r['compile_and_first_run_s']:.2f} |")
-    out.append("")
-    out.append(f"* scan-fused speedup (vmap): "
-               f"**{res.get('fused_speedup_vmap', 0.0):.2f}x**")
-    out.append("")
-    return "\n".join(out)
-
-
-def dryrun_section() -> str:
-    out = []
-    for mesh in ("single", "multi"):
-        recs = rl.load(mesh=mesh)
-        ok = sum(1 for r in recs if r.get("ok"))
-        out.append(f"* **{mesh}-pod mesh**: {ok}/{len(recs)} combinations "
-                   f"lower+compile OK"
-                   + ("" if ok == len(recs) else "  <-- FAILURES, see artifacts"))
-    out.append("")
-    out.append("Multi-pod status per combo (compile time, per-chip terms in "
-               "artifacts/dryrun/*__multi.json):")
-    out.append("")
-    out.append("| arch | train_4k | prefill_32k | decode_32k | long_500k |")
-    out.append("|---|---|---|---|---|")
-    recs = {(r["arch"], r["shape"]): r for r in rl.load(mesh="multi")}
-    archs = sorted({a for a, _ in recs})
-    for a in archs:
-        cells = []
-        for sh in ("train_4k", "prefill_32k", "decode_32k", "long_500k"):
-            r = recs.get((a, sh))
-            cells.append("OK" if r and r.get("ok") else "FAIL")
-        out.append(f"| {a} | " + " | ".join(cells) + " |")
-    return "\n".join(out)
-
-
-def roofline_section() -> str:
-    recs = rl.load(mesh="single")
-    out = [rl.format_table(recs), ""]
-    doms = rl.summarize(recs)
-    for dom, combos in sorted(doms.items()):
-        out.append(f"* **{dom}-bound** ({len(combos)}): {', '.join(combos)}")
-        out.append(f"  * lever: {rl.LEVERS[dom]}")
-    out.append("")
-    out.append(f"* hillclimb picks: {rl.pick_hillclimb_candidates(recs)}")
-    return "\n".join(out)
-
-
-PERF_LOG = r"""
-Three pairs (picked from the baseline table): **mixtral-8x7b/train_4k**
-(most collective-bound), **arctic-480b/train_4k** (worst roofline fraction),
-**qwen3-32b/train_4k** incl. its multi-pod DFL round (most representative of
-the paper's technique — the DecDiff pod-gossip runs in this step).  All
-numbers are per-chip seconds/step from the calibrated dry-run
-(artifacts/perf/*.json); variants via `dryrun.py --variant`.
-
-### mixtral-8x7b / train_4k  (baseline C 2.11 / M 24.98 / **Coll 30.85**)
-
-| # | hypothesis | change | result (C/M/Coll s) | verdict |
-|---|---|---|---|---|
-| 1 | activation psums stem from FSDP weight sharding; forcing use-site weight gather (ZeRO-3 constraint) will trade 45 GB of activation all-reduce for ~0.8 GB of weight all-gather | `zero3_gather` flag: re-constrain per-layer weight slices to model-only inside the scan | 16.62 / 64.55 / 72.59 | **REFUTED** — GSPMD resolved the conflicting constraint by replicating compute (8× flops). Reverted. |
-| 2 | the 9.4 GB fp32 per-layer all-reduce is the MoE global-capacity buffer crossing the batch sharding; batch-local dispatch keeps tokens on their shard | `moe_dispatch="batch_local"` — first as vmap (buffers replicated: only −20%), then explicit batch dim + constraints | 2.11 / 21.18 / **17.89** | **CONFIRMED** — collective −42%, memory −15%. vmap lesson: per-partition HLO shapes showed local B=256 (replicated) until the batch dim was explicit. |
-| 3 | fp32 attention probs are the largest remaining buffer; casting to bf16 before the combine halves that traffic | `attn_probs_bf16` | 2.11 / 21.35 / 17.89 | **REFUTED** — no change; scores/softmax stay fp32 and the cast adds a conversion pass. |
-| 4 | seq-sharding the scan carry removes the residual psum chain | `moelocal+seqshard` | 2.10 / 18.73 / 16.33 | **CONFIRMED (small)** — final: collective −47%, memory −25% vs baseline. |
-
-### arctic-480b / train_4k  (baseline C 3.58 / **M 31.59** / Coll 27.47)
-
-| # | hypothesis | change | result | verdict |
-|---|---|---|---|---|
-| 1 | mixtral's batch-local dispatch transfers | `moelocal` | 5.23 / 48.77 / 32.37 | **REFUTED** — the tradeoff flips: arctic's 13.4 B params/layer of expert weights make the forced weight-gather (26.8 GB/layer) far worse than the activation psum. Expert count changes the optimum. |
-| 2 | true expert parallelism (E=128 % 16 == 0): experts sharded over model, tokens all-to-all | `expertpar` (E-dim sharding rule + buffer constraints) | 5.22 / 41.78 / 25.00 | **REFUTED overall** — collective −9% but memory +32% (fp32 dispatch buffers + per-row capacity rounding). The baseline "TP-inside-experts" never moves weights and is already decent. |
-| 3 | per-layer saved residual dominates; seq-shard the carry | `seqshard` | **2.44 / 24.63 / 22.39** | **CONFIRMED** — all three terms down (compute −32%, memory −22%, collective −18%); bytes/device 164 -> 92 GB. |
-
-### qwen3-32b / train_4k + multi-pod DFL  (baseline single C 4.58 / **M 30.44** / Coll 10.02; multi C 2.27 / M 10.18 / Coll 4.81)
-
-| # | hypothesis | change | result | verdict |
-|---|---|---|---|---|
-| 1 | the [16,4096,5120] bf16 carry saved per layer (×64) is the memory wall; seq-sharding it over model removes both the capacity and the psum chain | `seqshard` (single-pod) | 4.54 / **15.50 / 1.08** | **CONFIRMED, biggest single win** — memory −49%, collective −89% (all-reduce 475 -> 37 GB/chip), bytes/device 131.6 -> 28.4 GB. |
-| 2 | same for the multi-pod DFL round | `seqshard` (multi) | 2.27 / 9.17 / 4.76 | **PARTIAL** — only −10% memory; the vmapped round keeps its activation psums. sdy dumps show the constraints ARE correctly pod-prefixed (verified `spmd_axis_name`, now enabled) — GSPMD chooses a different global solution when the gossip einsum consumes the stacked params. Open item. |
-| 3 | manual-pod shard_map round (explicit adjacency-masked ppermute ring per DESIGN.md §3) sidesteps GSPMD's choice | `build_dfl_round_shardmap` | — | **BLOCKED** — XLA SPMD partitioner CHECK failure (spmd_partitioner_util.cc:504) on the (2,16,16) partial-auto mesh; implementation kept (works on small meshes), documented as toolchain-blocked. |
-| 4 | bf16 gossip halves the paper's exchange volume | `gossipbf16` | no measurable change | **CONFIRMED-IRRELEVANT** — napkin + measurement agree: DecDiff gossip volume is params/chip ≈ 0.25 GB ≈ 5 ms vs a 4.8 s round. At pod scale the paper's "parameters-only" exchange is already negligible; local training dominates. This *quantifies* the paper's communication-efficiency claim on real hardware. |
-
-**Stopping:** mixtral iterations 3-4 and arctic 2-3 brought <5%-per-change on
-their dominant terms after the confirmed wins; remaining headroom is in the
-`bytes accessed` proxy (fp32 softmax/score paths) and the multi-pod DFL psum
-question above.
-
-**Paper-faithful vs beyond-paper summary** (dominant-term seconds):
-
-| pair | baseline (faithful) | best variant | Δ |
-|---|---|---|---|
-| mixtral-8x7b/train_4k | Coll 30.85 | Coll 16.33 (moelocal+seqshard) | **−47%** |
-| arctic-480b/train_4k | Mem 31.59 | Mem 24.63 (seqshard) | **−22%** |
-| qwen3-32b/train_4k | Mem 30.44 | Mem 15.50 (seqshard) | **−49%** |
-"""
-
-
 def main():
     sections = []
     sections.append("""# EXPERIMENTS
@@ -705,11 +368,11 @@ def main():
 All results produced inside this (CPU-only, offline) container.  Real
 datasets are unavailable -> synthetic stand-ins (DESIGN.md §1, data gate);
 accuracy numbers are NOT the paper's absolute numbers — the claims validated
-are the paper's ordering/qualitative claims.  TPU numbers are *derived*
-(dry-run compile + v5e constants: 197 TF bf16, 819 GB/s HBM, 50 GB/s/link
-ICI), not measured.
+are the paper's ordering/qualitative claims.  The tables record accuracy,
+bytes on the wire and simulated seconds, never speed: speed on the chip is
+recorded in PERF_LEDGER.jsonl and PERF.md.
 
-Contents: §Repro · §Dry-run · §Roofline · §Perf.
+Contents: §Repro.
 
 ---
 
@@ -735,56 +398,18 @@ paper's real-data setting and we do not claim it; the validated statement is
 the ORDERING among methods.
 """)
     sections.append(repro_section())
-    eng = engine_section()
-    if eng:
-        sections.append(eng)
     dyn = dynamics_section()
     if dyn:
         sections.append(dyn)
     tim = time_section()
     if tim:
         sections.append(tim)
-    obs = obs_section()
-    if obs:
-        sections.append(obs)
-    sections.append("""
-## §Dry-run — (10 archs × 4 shapes) × (single-pod 16x16, multi-pod 2x16x16)
-
-`PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both` — every
-combination must `.lower().compile()`.  Steps per shape: train_4k ->
-train_step (single) / DFL round with DecDiff pod-gossip (multi); prefill_32k
--> forward; decode shapes -> serve_step (1 token vs KV cache; long_500k uses
-the sub-quadratic path per DESIGN.md §4).
-
-**Methodology notes (each verified, see memory/dryrun-calibration-findings):**
-1. XLA's HloCostAnalysis counts `lax.scan` bodies ONCE — all roofline terms
-   come from calibration compiles (1/2 layers, scans unrolled, chunk grids
-   enlarged) extrapolated linearly; 3-point fit for the zamba2 hybrid.
-2. cost_analysis is per-partition; memory_analysis per-device; collective
-   bytes parsed from post-SPMD HLO (result-shape ÷/× group size).
-3. `bytes accessed` double-counts producer/consumer pairs — treat memory
-   terms as an upper bound (~2x), comparable across combos.
-4. The per-device `temp` from the CPU backend includes fp32 staging XLA:TPU
-   would fuse; `fits 16GB = NO` rows are upper-bound capacity flags, with
-   the §Perf seqshard variant the worst offenders drop 2-5x.
-""")
-    sections.append(dryrun_section())
-    sections.append("""
-## §Roofline — per (arch × shape), single-pod, per chip per step
-""")
-    sections.append(roofline_section())
-    sections.append("""
-## §Perf — hypothesis → change → measure → validate
-""")
-    sections.append(PERF_LOG)
 
     path = os.path.join(ROOT, "EXPERIMENTS.md")
     with open(path, "w") as f:
         f.write("\n".join(sections))
     print("wrote", path)
-    for p in (write_bench_comm(), write_bench_engine(),
-              write_bench_dynamics(), write_bench_time(),
-              write_bench_obs()):
+    for p in (write_bench_comm(), write_bench_dynamics(), write_bench_time()):
         if p:
             print("wrote", p)
 

@@ -3,8 +3,10 @@
     PYTHONPATH=src python -m benchmarks.run [--full]
 
 Prints a ``name,us_per_call,derived`` CSV summary at the end (per-benchmark
-detail printed as it runs).  --full uses paper-closer settings (3 datasets,
-more rounds); the default is sized for this 2-core CPU container.
+detail printed as it runs; ``us_per_call`` is the host wall time a section
+took, not a speed measurement: speed on the chip is recorded in
+PERF_LEDGER.jsonl and PERF.md).  --full uses paper-closer settings (3
+datasets, more rounds); the default is sized for a small CPU host.
 """
 from __future__ import annotations
 
@@ -26,37 +28,6 @@ def main() -> None:
 
     def record(name, t0, derived):
         csv.append((name, f"{(time.time() - t0) * 1e6:.0f}", derived))
-
-    # --- kernels (fast) -------------------------------------------------
-    from benchmarks import bench_kernels
-
-    t0 = time.time()
-    rows = bench_kernels.run()
-    record("kernels", t0, f"{len(rows)} shapes vs TPU roofline")
-
-    # --- engine runner (scan-fused vs per-round loop) -------------------
-    from benchmarks import bench_engine
-
-    t0 = time.time()
-    eng = bench_engine.run(verbose=False)
-    record("engine_runner", t0,
-           f"scan-fused {eng['fused_speedup_vmap']:.2f}x vs per-round loop")
-
-    # --- node-axis scaling (dense vs sparse layout) ---------------------
-    from benchmarks import bench_scale
-
-    t0 = time.time()
-    # the reduced lane runs the smoke sweep (scale_smoke artifact) so a
-    # down-scaled pass never clobbers the committed BENCH_scale.json;
-    # --full refreshes the real artifact + BENCH verdict.
-    sc = bench_scale.run(smoke=not args.full, verbose=False)
-    sparse_rows = [r for r in sc["rows"]
-                   if r["layout"] == "sparse" and "rounds_per_sec" in r]
-    top = max(sparse_rows, key=lambda r: r["nodes"])
-    record("scale", t0,
-           f"sparse n={top['nodes']} {top['rounds_per_sec']:.2f} rounds/s; "
-           f"builder n={sc['builder']['nodes']} "
-           f"{sc['builder']['wall_s']:.1f}s")
 
     # --- dynamics suite (time-varying topologies) -----------------------
     from benchmarks import bench_dynamics
@@ -97,22 +68,6 @@ def main() -> None:
            f"{tchal['sim_time']:.0f}s simulated (ba, "
            f"dAcc={tchal['acc_mean'] - tbase['acc_mean']:+.3f})")
 
-    # --- telemetry overhead + ledger/trace (repro.obs) ------------------
-    from benchmarks import bench_obs
-
-    t0 = time.time()
-    # same smoke convention: the reduced lane writes obs_smoke only;
-    # --full refreshes the obs_suite artifact behind BENCH_obs.json.
-    if args.full:
-        obs = bench_obs.run(verbose=False)
-    else:
-        obs = bench_obs.run(rounds=8, eval_every=4, verbose=False,
-                            smoke=True)
-    record("obs_telemetry", t0,
-           f"all-channels overhead {obs['overhead_frac'] * 100:+.1f}% "
-           f"(gate <=5%), trace bytes "
-           f"{'exact' if obs['trace']['bytes_exact'] else 'MISMATCH'}")
-
     # --- comm table (paper §VI-A.3) ------------------------------------
     from benchmarks import bench_comm
 
@@ -124,18 +79,6 @@ def main() -> None:
               and "mlp" in r["model"] and r["codec"] == "fp32")
     record("comm_table", t0,
            f"cfa-ge/decdiff+vt bytes ratio={ge['bytes_per_round']/dd['bytes_per_round']:.1f}x")
-
-    # --- roofline over dry-run artifacts (deliverable g) ----------------
-    from benchmarks import roofline
-
-    t0 = time.time()
-    recs = roofline.load()
-    if recs:
-        ok = sum(1 for r in recs if r.get("ok"))
-        print(roofline.format_table(recs))
-        record("roofline", t0, f"{ok}/{len(recs)} single-pod combos ok")
-    else:
-        record("roofline", t0, "no dryrun artifacts (run repro.launch.dryrun)")
 
     if not args.skip_sim:
         # --- Fig. 1 disruption ------------------------------------------

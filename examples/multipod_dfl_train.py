@@ -12,11 +12,11 @@ real pod axis, scan-fused into one XLA program per schedule.
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python examples/multipod_dfl_train.py --nodes 8
 
-For the assigned LM architectures the identical round shape lowers through
-`repro.dist.dfl_step.build_dfl_round_shardmap` (launch/dryrun.py --mesh
-multi), where the all_gather carries the encoded int8 payload and the
-dequantize+average is fused into the `dequant_neighbor_avg_rows` Pallas
-kernel — that path is exercised by the dry-run, not this example.
+For the LM architectures the same round shape lowers through the legacy
+`repro.dist.dfl_step.build_dfl_round_shardmap`, where the all_gather carries
+the encoded int8 payload and the dequantize+average is fused into the
+`dequant_neighbor_avg_rows` Pallas kernel — that path is pinned by
+tests/test_dist.py, not run by this example.
 """
 import argparse
 import os

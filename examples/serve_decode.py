@@ -2,7 +2,7 @@
 
 Loads a reduced assigned architecture, prefills a batch of synthetic prompts
 via the teacher-forced path, then decodes new tokens step by step (ring-
-buffer cache, one serve_step per token) — the long_500k path in miniature.
+buffer cache, one jitted `decode_step` per token).
 
     PYTHONPATH=src python examples/serve_decode.py --arch mixtral-8x7b
 """

@@ -95,19 +95,6 @@ def constrain_logits(x):
     return _constrain(mesh, x, spec)
 
 
-def constrain_expert_sharded(h):
-    """MoE dispatch buffers [B, E, C, D] under expert parallelism: experts
-    over the model axis (forces the slot all-to-all), batch over data."""
-    mesh = current_mesh()
-    if mesh is None or h.ndim < 2:
-        return h
-    manual = _manual_axes()
-    spec = [None] * h.ndim
-    spec[0] = _batch_axes(mesh, h.shape[0], manual)
-    spec[1] = _axis(mesh, MODEL_AXIS, h.shape[1], manual)
-    return _constrain(mesh, h, spec)
-
-
 def gather_weights(layer_params):
     """ZeRO-3 style: constrain one layer's weights to replicated inside the
     scan body, so GSPMD materializes each layer with a just-in-time

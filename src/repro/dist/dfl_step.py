@@ -1,12 +1,12 @@
-"""Jit-able steps: train/prefill/serve and the pod-level DFL round.
+"""Jit-able steps: the single-replica train step and the pod-level DFL round.
 
 The DFL round is the paper's Algorithm 1 executed over a stacked node axis:
 every node takes a local SGD step on its own shard of the synthetic stream,
 then DecDiff gossip (core/decdiff.py, Eq. 5-6) moves each node toward its
 neighbourhood average with the distance-attenuated step.  Two executions:
 
-  * `build_dfl_round`          — vmap over the node axis; on the production
-    mesh the node dim shards over "pod" via in_shardings (launch/dryrun.py).
+  * `build_dfl_round`          — vmap over the node axis; on a mesh with a
+    "pod" axis the node dim shards over it via in_shardings.
   * `build_dfl_round_shardmap` — explicit shard_map over the "pod" axis:
     neighbour models move with an all_gather over the pod ring and each pod
     applies Eq. 5-6 to its own nodes (see its docstring for the manual-axes
@@ -135,26 +135,6 @@ def build_train_step(lm, opt, *, loss_kind: str = "vt", beta: float = 0.98):
     """(params, opt_state, step, batch) -> (params, opt_state, loss) for a
     single model replica (data-parallel / centralized reference)."""
     return _make_node_step(lm, opt, loss_kind, beta)
-
-
-def build_prefill_step(lm):
-    """(params, batch) -> logits — the forward pass, teacher-forced."""
-
-    def prefill_step(params, batch):
-        logits, _ = lm.forward(params, batch)
-        return logits
-
-    return prefill_step
-
-
-def build_serve_step(lm):
-    """(params, cache, tokens [B, 1]) -> (logits, cache) — one decode step
-    against the ring-buffer KV / recurrent cache."""
-
-    def serve_step(params, cache, tokens):
-        return lm.decode_step(params, cache, tokens)
-
-    return serve_step
 
 
 def build_dfl_round(lm, opt, adj, *, loss_kind: str = "vt", beta: float = 0.98,

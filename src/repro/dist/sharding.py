@@ -1,6 +1,6 @@
-"""PartitionSpec inference for the production mesh.
+"""PartitionSpec inference for a ("data", "model") device mesh.
 
-Mesh convention (launch/mesh.py):
+Mesh convention:
 
   * "data"  — batch / data parallelism,
   * "model" — tensor parallelism (weights and feature dims),
@@ -39,10 +39,6 @@ SMALL_LEAF_ELEMS = 1 << 16
 # Keys whose subtrees carry stacked per-layer params with this many leading
 # stack dims ([L, ...] from vmapped init; zamba's mamba blocks are [G, E, ...]).
 _STACK_LEAD = {"layers": 1, "enc_layers": 1, "dec_layers": 1, "mamba": 2}
-
-# MoE expert weights [L, E, D, F]: with expert parallelism the E dim shards
-# over "model" (experts live on model shards; dispatch becomes an all-to-all).
-_EXPERT_KEYS = {"wg", "wu", "wd"}
 
 
 def make_mesh(shape, axes, *, devices=None) -> Mesh:
@@ -115,8 +111,7 @@ def _path_keys(path):
     return keys
 
 
-def make_param_specs(params, mesh, *, dfl_node_axis: bool = False,
-                     expert_parallel: bool = False):
+def make_param_specs(params, mesh, *, dfl_node_axis: bool = False):
     """PartitionSpecs for a parameter pytree (same structure, P leaves).
 
     With `dfl_node_axis=True` every leaf carries a leading per-node stack dim
@@ -129,22 +124,8 @@ def make_param_specs(params, mesh, *, dfl_node_axis: bool = False,
         n_stack = max((_STACK_LEAD.get(k, 0) for k in keys), default=0)
         n_lead = int(dfl_node_axis) + n_stack
         shape = tuple(int(d) for d in leaf.shape)
-        e_dim = n_lead
-        if (expert_parallel and keys and keys[-1] in _EXPERT_KEYS
-                and len(shape) > e_dim
-                and shape[e_dim] % _axis_size(mesh, MODEL_AXIS) == 0):
-            spec = [None] * len(shape)
-            spec[e_dim] = MODEL_AXIS
-            rest = sorted(range(e_dim + 1, len(shape)),
-                          key=lambda i: (-shape[i], i))
-            data_dim = next(
-                (i for i in rest if shape[i] % _axis_size(mesh, DATA_AXIS) == 0),
-                None)
-            if data_dim is not None:
-                spec[data_dim] = DATA_AXIS
-        else:
-            spec = list(leaf_spec(shape, leaf.dtype, n_lead,
-                                  DATA_AXIS, MODEL_AXIS, mesh))
+        spec = list(leaf_spec(shape, leaf.dtype, n_lead,
+                              DATA_AXIS, MODEL_AXIS, mesh))
         if (dfl_node_axis and shape and NODE_AXIS in mesh.shape
                 and shape[0] % pod_n == 0):
             spec[0] = NODE_AXIS

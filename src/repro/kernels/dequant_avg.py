@@ -1,20 +1,19 @@
-"""Pallas kernel: fused int8-dequantize + weighted neighbour average.
+"""Pallas kernel: fused int8-dequantize + weighted neighbour average for a
+block of receivers.
 
-The comm layer's int8 gossip hands each node N quantized neighbour rows
+The comm layer's int8 gossip hands each receiver N quantized neighbour rows
 (q [N, D] int8, one fp32 scale per row).  Materializing the dequantized
 fp32 models in HBM would cost 4x the payload's footprint and a full extra
 HBM round-trip; instead this kernel folds the dequantization into the
 Eq. 6 reduction:
 
-    out[d] = Σ_n (w[n] * scale[n]) * q[n, d]
+    out[r, d] = Σ_n (wn[r, n] * scale[n]) * q[n, d]
 
-Same tiling as neighbor_avg (the codec changes the wire format, not the
-aggregation geometry): D streams in (N, COLS) tiles — int8 rows are 4x
-denser per tile, so one tile = N*2048 bytes <= 128 KiB VMEM — and the
-per-row weight*scale product collapses into the einsum vector, keeping the
-inner loop a single int8->fp32 vector-matrix product on the VPU.
+D streams in (N, COLS) tiles — int8 rows are 4x denser per tile, so one
+tile = N*2048 bytes — and the per-sender weight*scale product folds into
+the weight matrix, keeping the inner loop one int8->fp32 matrix product.
 
-Weights are pre-normalized by the wrapper (ops.dequant_neighbor_avg).
+Weights are pre-normalized by the caller (ops.dequant_neighbor_avg_rows).
 """
 from __future__ import annotations
 
@@ -23,33 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 COLS = 2048
-
-
-def _dequant_avg_kernel(q_ref, ws_ref, out_ref):
-    # ws = weight * scale per row: dequantization is just a per-row rescale,
-    # so it fuses into the reduction weights for free.
-    out_ref[...] = jnp.einsum(
-        "n,nd->d", ws_ref[...], q_ref[...].astype(jnp.float32),
-        preferred_element_type=jnp.float32)
-
-
-def dequant_avg_blocks(q: jnp.ndarray, weight_scale: jnp.ndarray, *,
-                       interpret: bool = False) -> jnp.ndarray:
-    """q [N, D] int8, weight_scale [N] fp32 (= normalized weight x scale)
-    -> [D] fp32 weighted dequantized average."""
-    n, d = q.shape
-    assert d % COLS == 0, d
-    return pl.pallas_call(
-        _dequant_avg_kernel,
-        grid=(d // COLS,),
-        in_specs=[
-            pl.BlockSpec((n, COLS), lambda i: (0, i)),
-            pl.BlockSpec((n,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((COLS,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((d,), jnp.float32),
-        interpret=interpret,
-    )(q, weight_scale)
 
 
 def _dequant_avg_rows_kernel(q_ref, ws_ref, out_ref):
@@ -69,9 +41,8 @@ def dequant_avg_rows_blocks(q: jnp.ndarray, ws: jnp.ndarray, *,
 
     q [N, D] int8 (the all_gathered wire payloads), ws [R, N] fp32
     (= row-normalized gossip weights x per-sender scales) -> [R, D] fp32
-    weighted dequantized averages.  Same (N, COLS) streaming as the
-    single-receiver kernel — each q tile is loaded once and reused for all
-    R receivers, which is the point of fusing across the block.
+    weighted dequantized averages.  Each q tile is loaded once and reused
+    for all R receivers, which is the point of fusing across the block.
     """
     n, d = q.shape
     r = ws.shape[0]

@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Handles padding to tile boundaries, the pytree <-> flat-stream view, the
-custom_vjp wiring for the fused VT loss, and the `interpret` default: kernels
-compile for the TPU and run in interpret mode on the CPU, where the tests
-check them (see `_interpret_default`).
+Handles padding to tile boundaries, the custom_vjp wiring for the fused VT
+loss, and the `interpret` default: kernels compile for the TPU and run in
+interpret mode on the CPU, where the tests check them (see
+`_interpret_default`).
 """
 from __future__ import annotations
 
@@ -13,10 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.virtual_teacher import teacher_entropy
-from repro.kernels import decdiff_update as _dd
-from repro.kernels import neighbor_avg as _na
 from repro.kernels import vt_kl_loss as _vt
-from repro.utils.pytree import tree_flatten_to_vector
 
 
 def _interpret_default() -> bool:
@@ -28,40 +25,6 @@ def _interpret_default() -> bool:
             f"Pallas kernels compile for the TPU and run interpreted on the "
             f"CPU; backend {backend!r} is neither")
     return backend == "cpu"
-
-
-def _pad_to(x, multiple, value=0.0):
-    n = x.shape[0]
-    pad = (-n) % multiple
-    if pad:
-        x = jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1), constant_values=value)
-    return x
-
-
-# ------------------------------------------------------------- decdiff
-
-
-@functools.partial(jax.jit, static_argnames=("s", "interpret"))
-def decdiff_update(w_flat, wbar_flat, s: float = 1.0, interpret=None):
-    """Eq. 5 on flat vectors via the two-pass Pallas stream."""
-    interpret = _interpret_default() if interpret is None else interpret
-    n = w_flat.shape[0]
-    tile = _dd.BLOCK_ROWS * _dd.LANES
-    w = _pad_to(w_flat.astype(jnp.float32), tile).reshape(-1, _dd.LANES)
-    wb = _pad_to(wbar_flat.astype(jnp.float32), tile).reshape(-1, _dd.LANES)
-    # pad region contributes (wb-w)=0 to the norm because both pads are 0.
-    partials = _dd.sumsq_diff_blocks(w, wb, interpret=interpret)
-    d = jnp.sqrt(jnp.sum(partials))
-    scale = (1.0 / (d + s)).reshape(1, 1)
-    out = _dd.scaled_step_blocks(w, wb, scale, interpret=interpret)
-    return out.reshape(-1)[:n].astype(w_flat.dtype)
-
-
-def decdiff_update_tree(params, avg_params, s: float = 1.0, interpret=None):
-    """Pytree-level DecDiff step backed by the flat-stream kernel."""
-    w, unflatten = tree_flatten_to_vector(params)
-    wbar, _ = tree_flatten_to_vector(avg_params)
-    return unflatten(decdiff_update(w, wbar, s=s, interpret=interpret))
 
 
 # ------------------------------------------------------------- vt loss
@@ -122,69 +85,7 @@ def _vt_bwd(beta, interpret, res, g):
 vt_kl_loss_fused.defvjp(_vt_fwd, _vt_bwd)
 
 
-# ------------------------------------------------------------- decode attn
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_attention_fused(q, k_cache, v_cache, slot_pos, pos, interpret=None):
-    """Fused one-token GQA attention over a ring cache (serve hot spot).
-
-    q [B,H,hd]; k/v [B,W,K,hd]; slot_pos [W] absolute positions (-1 empty);
-    pos scalar current position.  Matches layers.decode_attention's
-    score/softmax/combine (output fp32)."""
-    from repro.kernels import decode_attention as _da
-
-    interpret = _interpret_default() if interpret is None else interpret
-    b, h, hd = q.shape
-    w = k_cache.shape[1]
-    pad_b = (-b) % _da.B_BLK
-    pad_w = (-w) % _da.W_BLK
-    qp = jnp.pad(q.astype(jnp.float32), ((0, pad_b), (0, 0), (0, 0)))
-    kp = jnp.pad(k_cache, ((0, pad_b), (0, pad_w), (0, 0), (0, 0)))
-    vp = jnp.pad(v_cache, ((0, pad_b), (0, pad_w), (0, 0), (0, 0)))
-    spp = jnp.pad(slot_pos.astype(jnp.int32), (0, pad_w), constant_values=-1)
-    pos2 = jnp.reshape(pos.astype(jnp.int32), (1, 1))
-    out = _da.decode_attention_blocks(qp, kp, vp, spp, pos2,
-                                      interpret=interpret)
-    return out[:b]
-
-
 # ------------------------------------------------------------- neighbor avg
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def neighbor_avg(stacked, weights, interpret=None):
-    """Eq. 6: normalized ω_ij p_ij-weighted average of stacked [N, D] rows."""
-    interpret = _interpret_default() if interpret is None else interpret
-    n, d = stacked.shape
-    w = weights.astype(jnp.float32)
-    w = w / jnp.sum(w)
-    pad = (-d) % _na.COLS
-    sp = jnp.pad(stacked.astype(jnp.float32), ((0, 0), (0, pad)))
-    out = _na.neighbor_avg_blocks(sp, w, interpret=interpret)
-    return out[:d]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def dequant_neighbor_avg(q, scales, weights, interpret=None):
-    """Eq. 6 over int8 comm payloads: dequantize-and-accumulate in one pass.
-
-    q [N, D] int8 rows (the neighbours' wire payloads), scales [N] fp32
-    per-row quantization scales, weights [N] ω_ij p_ij (normalized here).
-    Equals neighbor_avg(q * scales[:, None], weights) without ever writing
-    the dequantized models back to HBM.
-    """
-    from repro.kernels import dequant_avg as _dqa
-
-    interpret = _interpret_default() if interpret is None else interpret
-    n, d = q.shape
-    w = weights.astype(jnp.float32)
-    w = w / jnp.sum(w)
-    ws = w * scales.astype(jnp.float32)
-    pad = (-d) % _dqa.COLS
-    qp = jnp.pad(q.astype(jnp.int8), ((0, 0), (0, pad)))
-    out = _dqa.dequant_avg_blocks(qp, ws, interpret=interpret)
-    return out[:d]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -5,14 +5,6 @@ import jax
 import jax.numpy as jnp
 
 
-def decdiff_update_ref(w: jnp.ndarray, wbar: jnp.ndarray, s: float = 1.0):
-    """Eq. 5 on flat fp32 vectors: w + (wbar-w)/(||wbar-w|| + s)."""
-    w32 = w.astype(jnp.float32)
-    diff = wbar.astype(jnp.float32) - w32
-    d = jnp.sqrt(jnp.sum(diff * diff))
-    return (w32 + diff / (d + s)).astype(w.dtype)
-
-
 def vt_kl_loss_ref(logits: jnp.ndarray, labels: jnp.ndarray, beta: float):
     """Eq. 8 mean KL(p_t || softmax(z)) with the materialized teacher."""
     z = logits.astype(jnp.float32)
@@ -34,39 +26,6 @@ def vt_kl_grad_ref(logits: jnp.ndarray, labels: jnp.ndarray, beta: float):
     p_t = onehot * beta + (1.0 - onehot) * a
     p = jax.nn.softmax(z, axis=-1)
     return (p - p_t) / z.shape[0]
-
-
-def decode_attention_ref(q, k_cache, v_cache, slot_pos, pos):
-    """One-token GQA attention over a ring cache — mirrors
-    repro.models.lm.layers.decode_attention's math (fp32)."""
-    q32 = q.astype(jnp.float32)
-    b, h, hd = q32.shape
-    kk = k_cache.shape[2]
-    g = h // kk
-    qg = q32.reshape(b, kk, g, hd)
-    scale = 1.0 / (hd ** 0.5)
-    s = jnp.einsum("bkgd,bwkd->bkgw", qg, k_cache.astype(jnp.float32)) * scale
-    ok = (slot_pos >= 0) & (slot_pos <= pos)
-    s = jnp.where(ok[None, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgw,bwkd->bkgd", p, v_cache.astype(jnp.float32))
-    return out.reshape(b, h, hd)
-
-
-def neighbor_avg_ref(stacked: jnp.ndarray, weights: jnp.ndarray):
-    """Eq. 6 on a stacked [N, D] matrix: normalized weighted average."""
-    w = weights.astype(jnp.float32)
-    w = w / jnp.sum(w)
-    return jnp.einsum("n,nd->d", w, stacked.astype(jnp.float32))
-
-
-def dequant_neighbor_avg_ref(q: jnp.ndarray, scales: jnp.ndarray,
-                             weights: jnp.ndarray):
-    """Eq. 6 over int8 payloads: dequantize rows, then normalized average."""
-    w = weights.astype(jnp.float32)
-    w = w / jnp.sum(w)
-    dq = q.astype(jnp.float32) * scales.astype(jnp.float32)[:, None]
-    return jnp.einsum("n,nd->d", w, dq)
 
 
 def dequant_neighbor_avg_rows_ref(q: jnp.ndarray, scales: jnp.ndarray,

@@ -7,9 +7,10 @@ Two modes:
   * ``--mode single``: plain data-parallel training (the "centralized"
     reference at the systems level).
 
-On real hardware this runs under the production mesh (launch/mesh.py); on
-this container it runs the reduced configs on the host CPU mesh.  Synthetic
-token streams stand in for the data pipeline (repro.data.tokens).
+This is the driver of the legacy LM round (`repro.dist.dfl_step`); the
+engine's rounds go through `repro.engine.Experiment`.  It runs the reduced
+configs on the host CPU.  Synthetic token streams stand in for the data
+pipeline (repro.data.tokens).
 
 Example (CPU, ~100M-params-class run):
   PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \

@@ -69,16 +69,11 @@ class ArchConfig:
     param_dtype: str = "bfloat16"
     activation_dtype: str = "bfloat16"
     remat: bool = True
-    # Fully unroll lax.scan loops.  Used by the dry-run calibration compiles:
-    # XLA's HloCostAnalysis counts while-loop bodies once (not x trip-count),
-    # so per-layer costs are measured on small unrolled configs and
-    # extrapolated (launch/dryrun.py).
-    scan_unroll: bool = False
 
-    # ---- §Perf levers (default off = paper-faithful baseline) ----
+    # ---- sharding levers (default off = paper-faithful baseline) ----
     # ZeRO-3 use-site weight gather: constrain per-layer weight slices to
     # model-axis-only sharding so GSPMD all-gathers weights over data rather
-    # than psum-ing activations (EXPERIMENTS.md §Perf iteration 1).
+    # than psum-ing activations.
     zero3_gather: bool = False
     # Residual-stream/scan-carry sharding: "batch" (baseline) or "batch_seq"
     # (seq dim sharded over model between layers — memory-capacity lever).
@@ -88,13 +83,8 @@ class ArchConfig:
     attn_probs_bf16: bool = False
     # MoE dispatch strategy: "global" capacity pool (baseline) or
     # "batch_local" (per-row dispatch; expert buffers stay batch-sharded —
-    # kills the global-buffer all-reduce, see §Perf).
+    # kills the global-buffer all-reduce).
     moe_dispatch: str = "global"
-    # Expert parallelism: shard the expert dim of expert weights (and the
-    # dispatch buffers) over the model axis when E % axis == 0.  The dispatch
-    # becomes an all-to-all of activations instead of gathering the (huge)
-    # expert weights — the right trade for many-expert models (arctic).
-    expert_parallel: bool = False
 
     # serving
     decode_window: Optional[int] = None  # ring-buffer cache size for long ctx

@@ -72,7 +72,7 @@ def trunk(cfg: ArchConfig, params, x, positions):
         return layer_apply(cfg, lp, h, positions), None
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, _ = jax.lax.scan(body_fn, x, params["layers"], unroll=cfg.scan_unroll)
+    x, _ = jax.lax.scan(body_fn, x, params["layers"])
     return apply_norm(cfg, x, params["final_norm"])
 
 
@@ -108,8 +108,7 @@ def decode_step_dense(cfg: ArchConfig, params, cache, tokens):
         return h, lc_new
 
     layer_caches = {"k": cache["k"], "v": cache["v"], "slot_pos": cache["slot_pos"]}
-    x, new_caches = jax.lax.scan(body, x, (params["layers"], layer_caches),
-                                 unroll=cfg.scan_unroll)
+    x, new_caches = jax.lax.scan(body, x, (params["layers"], layer_caches))
     x = apply_norm(cfg, x, params["final_norm"])
     logits = unembed(cfg, params.get("unembed"), params["embed"], x)
     new_cache = dict(new_caches, length=length + 1)

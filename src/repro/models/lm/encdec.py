@@ -92,7 +92,7 @@ def encode(cfg: ArchConfig, params, enc_embeds):
         return h, None
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, _ = jax.lax.scan(body_fn, x, params["enc_layers"], unroll=cfg.scan_unroll)
+    x, _ = jax.lax.scan(body_fn, x, params["enc_layers"])
     return apply_norm(cfg, x, params["enc_final_norm"])
 
 
@@ -116,7 +116,7 @@ def decode_train(cfg: ArchConfig, params, tokens, enc_out):
         return h, None
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, _ = jax.lax.scan(body_fn, x, params["dec_layers"], unroll=cfg.scan_unroll)
+    x, _ = jax.lax.scan(body_fn, x, params["dec_layers"])
     x = apply_norm(cfg, x, params["final_norm"])
     return constrain_logits(unembed(cfg, params.get("unembed"), params["embed"], x))
 
@@ -178,7 +178,7 @@ def decode_step_encdec(cfg: ArchConfig, params, cache, tokens):
     x, (nk, nv, nsp) = jax.lax.scan(
         body, x,
         (params["dec_layers"], cache["k"], cache["v"], cache["slot_pos"],
-         cache["cross_k"], cache["cross_v"]), unroll=cfg.scan_unroll)
+         cache["cross_k"], cache["cross_v"]))
     x = apply_norm(cfg, x, params["final_norm"])
     logits = unembed(cfg, params.get("unembed"), params["embed"], x)
     new_cache = dict(cache, k=nk, v=nv, slot_pos=nsp, length=length + 1)

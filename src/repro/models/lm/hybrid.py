@@ -100,7 +100,7 @@ def forward_hybrid(cfg: ArchConfig, params, tokens, positions=None):
             return ssm_block(cfg, lp, hh), None
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        h, _ = jax.lax.scan(body_fn, h, group_layers, unroll=cfg.scan_unroll)
+        h, _ = jax.lax.scan(body_fn, h, group_layers)
         return h
 
     for gi in range(g):
@@ -147,8 +147,7 @@ def decode_step_hybrid(cfg: ArchConfig, params, cache, tokens):
             return h, (conv_n, st_n)
 
         x, (conv_n, state_n) = jax.lax.scan(
-            body, x, (group_layers, conv_all[gi], state_all[gi]),
-            unroll=cfg.scan_unroll)
+            body, x, (group_layers, conv_all[gi], state_all[gi]))
         new_conv.append(conv_n)
         new_state.append(state_n)
         # shared attention block on the single token

@@ -167,7 +167,7 @@ def _plain_attention(cfg, q, k, v, q_pos, k_pos, causal, window):
     bias = _mask_bias(q_pos, k_pos, causal, window)  # [Sq,Sk], broadcasts
     probs = jax.nn.softmax(scores + bias, axis=-1)
     if cfg.attn_probs_bf16:
-        probs = probs.astype(jnp.bfloat16)  # §Perf: halve the S^2 traffic
+        probs = probs.astype(jnp.bfloat16)  # halve the S^2 traffic
     return _gqa_combine(probs, v).astype(q.dtype)
 
 
@@ -175,8 +175,7 @@ def _chunked_attention(cfg, q, k, v, q_pos, k_pos, causal, window):
     """Flash-style two-level scan with online softmax.
 
     Baseline computes every (q-chunk, kv-chunk) pair and relies on masking for
-    causality (fully-masked pairs are wasted FLOPs — see EXPERIMENTS.md §Perf
-    for the causal-skip iteration)."""
+    causality (fully-masked pairs are wasted FLOPs)."""
     b, sq, h, hd = q.shape
     sk = k.shape[1]
     kk = cfg.n_kv_heads
@@ -214,14 +213,12 @@ def _chunked_attention(cfg, q, k, v, q_pos, k_pos, causal, window):
         m0 = jnp.full((b, kk, g, qc), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, kk, g, qc), jnp.float32)
         a0 = jnp.zeros((b, kk, g, qc, hd), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(kv_body, (m0, l0, a0), (kr, vr, kpr),
-                                      unroll=cfg.scan_unroll)
+        (m, l, acc), _ = jax.lax.scan(kv_body, (m0, l0, a0), (kr, vr, kpr))
         out = acc / jnp.maximum(l[..., None], 1e-30)
         out = out.transpose(0, 3, 1, 2, 4).reshape(b, qc, h, hd)  # [B,qc,H,hd]
         return None, out.astype(q.dtype)
 
-    _, outs = jax.lax.scan(q_body, None, (qr, qpr),
-                           unroll=cfg.scan_unroll)  # [nq,B,qc,H,hd]
+    _, outs = jax.lax.scan(q_body, None, (qr, qpr))  # [nq,B,qc,H,hd]
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, hd)
 
 

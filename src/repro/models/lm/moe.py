@@ -10,10 +10,6 @@ Dispatch is capacity-based sorted scatter (Switch-style, token-dropping):
      (over capacity) fall through with zero contribution — the dense residual
      (arctic) or the residual stream still carries them.
 
-On the production mesh the [E, C, D] buffers shard over the model axis
-(expert-parallel) when E % axis == 0; the all-to-all this induces shows up in
-the dry-run collective schedule (§Roofline).
-
 A Switch-style load-balance auxiliary loss is returned alongside the layer
 output and surfaces in the train metrics (weight cfg.router_aux_weight).
 """
@@ -24,7 +20,6 @@ import jax.numpy as jnp
 
 from repro.dist.constraints import (
     constrain_batch,
-    constrain_expert_sharded,
     constrain_logits,
     constrain_residual,
     gather_weights,
@@ -71,9 +66,8 @@ def moe_ffn(cfg: ArchConfig, p, x):
       "global"      — one capacity pool over all B*S tokens (baseline;
                       faithful single-host formulation, but on the sharded
                       mesh the [E, C_global, D] buffers cross the batch
-                      sharding: measured 9.4 GB fp32 all-reduce per mixtral
-                      layer — EXPERIMENTS.md §Perf).
-      "batch_local" — §Perf variant: the sorted dispatch runs per batch row
+                      sharding, which costs an fp32 all-reduce per layer).
+      "batch_local" — the sorted dispatch runs per batch row
                       (vmap over B), so every data shard routes only its own
                       tokens and the expert buffers stay batch-sharded; no
                       cross-shard token motion.  Capacity is enforced per
@@ -91,7 +85,7 @@ def _moe_batch_local(cfg: ArchConfig, p, x):
     Every tensor carries B as dim0 with a sharding constraint, so tokens
     never leave their data shard; the expert-weight contraction then cannot
     psum over data (the output is batch-sharded) and GSPMD is forced into the
-    cheap per-layer weight all-gather instead (§Perf iteration log)."""
+    cheap per-layer weight all-gather instead."""
     b, s, d = x.shape
     k, e = cfg.top_k, cfg.n_experts
     xf = constrain_batch(x)
@@ -125,14 +119,9 @@ def _moe_batch_local(cfg: ArchConfig, p, x):
         gathered, mode="drop")
     buf = constrain_batch(buf)
     h = buf.reshape(b, e, cap, d)
-    if cfg.expert_parallel:
-        # all-to-all: move token slots to the model-shard owning their expert
-        h = constrain_expert_sharded(h)
     gate = jax.nn.silu(jnp.einsum("becd,edf->becf", h, p["wg"].astype(h.dtype)))
     up = jnp.einsum("becd,edf->becf", h, p["wu"].astype(h.dtype))
     y = jnp.einsum("becf,efd->becd", gate * up, p["wd"].astype(h.dtype))
-    if cfg.expert_parallel:
-        y = constrain_expert_sharded(y)
     y = constrain_batch(y.reshape(b, e * cap, d))
 
     w_sorted = (jnp.take_along_axis(top_w.reshape(b, s * k), order, axis=1)
@@ -236,7 +225,7 @@ def forward_moe(cfg: ArchConfig, params, tokens, positions=None):
         return h, aux
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, auxs = jax.lax.scan(body_fn, x, params["layers"], unroll=cfg.scan_unroll)
+    x, auxs = jax.lax.scan(body_fn, x, params["layers"])
     x = apply_norm(cfg, x, params["final_norm"])
     logits = constrain_logits(unembed(cfg, params.get("unembed"), params["embed"], x))
     return logits, jnp.mean(auxs)
@@ -261,8 +250,7 @@ def decode_step_moe(cfg: ArchConfig, params, cache, tokens):
         return h + y, lc_new
 
     layer_caches = {"k": cache["k"], "v": cache["v"], "slot_pos": cache["slot_pos"]}
-    x, new_caches = jax.lax.scan(body, x, (params["layers"], layer_caches),
-                                 unroll=cfg.scan_unroll)
+    x, new_caches = jax.lax.scan(body, x, (params["layers"], layer_caches))
     x = apply_norm(cfg, x, params["final_norm"])
     logits = unembed(cfg, params.get("unembed"), params["embed"], x)
     return logits, dict(new_caches, length=length + 1)

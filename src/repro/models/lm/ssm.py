@@ -148,8 +148,7 @@ def ssd_chunked(cfg: ArchConfig, x, b_mat, c_mat, a, state0=None):
         state_new = state * jnp.exp(total)[:, :, None, None] + s_add
         return state_new, (y_intra + y_inter).astype(x.dtype)
 
-    state, ys = jax.lax.scan(chunk_body, state0, (xc, bc_, cc_, ac),
-                             unroll=cfg.scan_unroll)
+    state, ys = jax.lax.scan(chunk_body, state0, (xc, bc_, cc_, ac))
     y = ys.transpose(1, 0, 2, 3, 4).reshape(bsz, s, h, p)
     return y, state
 
@@ -199,7 +198,7 @@ def forward_ssm(cfg: ArchConfig, params, tokens, positions=None):
         return ssm_block(cfg, lp, h), None
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, _ = jax.lax.scan(body_fn, x, params["layers"], unroll=cfg.scan_unroll)
+    x, _ = jax.lax.scan(body_fn, x, params["layers"])
     x = apply_norm(cfg, x, params["final_norm"])
     return constrain_logits(unembed(cfg, params.get("unembed"), params["embed"], x))
 
@@ -253,8 +252,7 @@ def decode_step_ssm(cfg: ArchConfig, params, cache, tokens):
         return h, (conv_new, st_new)
 
     x, (conv_new, state_new) = jax.lax.scan(
-        body, x, (params["layers"], cache["conv"], cache["state"]),
-        unroll=cfg.scan_unroll)
+        body, x, (params["layers"], cache["conv"], cache["state"]))
     x = apply_norm(cfg, x, params["final_norm"])
     logits = unembed(cfg, params.get("unembed"), params["embed"], x[:, None, :])
     return logits, {"conv": conv_new, "state": state_new, "length": cache["length"] + 1}

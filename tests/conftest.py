@@ -6,8 +6,8 @@ import pytest
 # src layout without installation
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# Tests run on the real (single) CPU device — the 512-device override is
-# dryrun.py-only by design.
+# Tests run on the real (single) CPU device unless a lane forces more
+# host devices through XLA_FLAGS.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 

@@ -1,4 +1,5 @@
-"""§Perf config variants must preserve model semantics (CPU, no mesh)."""
+"""The sharding-lever config variants must preserve model semantics (CPU,
+no mesh)."""
 import dataclasses
 
 import jax
@@ -30,7 +31,7 @@ def test_moe_batch_local_matches_global():
     {"moe_dispatch": "batch_local"},
 ])
 def test_variant_forward_close_to_baseline(overrides):
-    """Off-mesh, every §Perf lever is numerically (near-)neutral."""
+    """Off-mesh, every sharding lever is numerically (near-)neutral."""
     arch = "mixtral-8x7b" if "moe_dispatch" in overrides else "deepseek-7b"
     cfg = get_config(arch).reduced()
     if "moe_dispatch" in overrides:

@@ -210,12 +210,15 @@ def test_segment_avg_gather_bitwise_per_row(cols):
     assert np.array_equal(np.asarray(tot), np.asarray(_rows_ref(w, ones)))
 
 
-@pytest.mark.parametrize("k", [13, 17])
-def test_segment_neighbor_avg_rows_k_not_multiple_of_8(k):
-    """Degree widths that are not multiples of 8 (the dense max_deg of
-    other seeds and graphs) are zero-padded without moving a bit."""
-    rng = np.random.default_rng(10 + k)
-    table, idx, w = _gather_inputs(rng, 20, 11, k, 150)
+@pytest.mark.parametrize("d", [1, 100, 128, 150, 1000, 4099])
+@pytest.mark.parametrize("k", [1, 2, 8, 13, 16, 17, 33])
+def test_segment_neighbor_avg_rows_shapes(k, d):
+    """The engine's reduce over a grid of degree widths and model widths:
+    K below, at and past a multiple of 8 (zero-padded without moving a
+    bit), D below, at and past a lane multiple, bit-equal to the per-row
+    contract everywhere."""
+    rng = np.random.default_rng(10 + 7 * k + d)
+    table, idx, w = _gather_inputs(rng, 20, 11, k, d)
     sums, tot = segment_neighbor_avg_rows(table, idx, w)
     assert np.array_equal(np.asarray(sums),
                           np.asarray(_rows_ref(w, table[idx])))
@@ -307,9 +310,11 @@ def test_segment_neighbor_avg_totals_ride_the_contraction():
     assert np.allclose(np.asarray(tot), np.asarray(w).sum(axis=1), rtol=1e-6)
 
 
-def test_dequant_segment_neighbor_avg_matches_reference():
-    rng = np.random.default_rng(5)
-    b, k, d = 8, 8, 96
+@pytest.mark.parametrize("d", [1, 128, 4099])
+@pytest.mark.parametrize("k", [1, 16])
+def test_dequant_segment_neighbor_avg_matches_reference(k, d):
+    rng = np.random.default_rng(5 + k + d)
+    b = 11
     q = jnp.asarray(rng.integers(-127, 128, size=(b, k, d), dtype=np.int8))
     scales = jnp.asarray(rng.uniform(0.01, 0.1, (b, k)).astype(np.float32))
     w = jnp.asarray(rng.uniform(0.0, 2.0, (b, k)).astype(np.float32))
